@@ -78,8 +78,8 @@ def bench_fig3_factorization() -> None:
 
 
 #: Mobile-class VMEM budget for the streamed fig2 family: whole-T residency
-#: falls off it by T=512 (bwd) / T=2048 (fwd) at the seed config, so the
-#: rows demonstrate the time-chunked pipeline keeping the plan fused where
+#: falls off it by T=256 (fwd) and at every T (bwd) at the seed config, so
+#: the rows demonstrate the time-chunked pipeline keeping the plan fused where
 #: it previously fell back.  Shared with the acceptance tests via
 #: core/factorization so everything asserts one viability surface.
 STREAM_BUDGET = fz.MOBILE_VMEM_BUDGET
@@ -1432,4 +1432,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+    compile_cache.enable()
     main()

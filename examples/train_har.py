@@ -14,16 +14,75 @@ registered plans so the Fig 4 comparison covers the Pallas plans too.
       --layers 2 --plan fused_seq
 """
 import argparse
+import functools
 import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.configs.mobirnn_lstm import LSTMConfig
 from repro.core import lstm
 from repro.data import har
 from repro.optim import AdamW, warmup_cosine
+
+
+def train(cfg: LSTMConfig, forward, data: har.HARData, *, steps: int,
+          batch: int, lr: float, test: har.HARData | None = None,
+          seed: int = 0, log=print):
+    """AdamW training of the HAR classifier under one execution plan.
+    Returns ``(params, losses, step_s)``: the trained params, the loss of
+    every step and its wall seconds (the first includes the compile)."""
+    params = lstm.init_params(jax.random.PRNGKey(seed), cfg)
+    opt = AdamW(lr=warmup_cosine(lr, steps // 10, steps), weight_decay=0.0)
+    state = opt.init(params)
+
+    @jax.jit
+    def step(params, state, x, y):
+        loss, grads = jax.value_and_grad(lstm.loss_fn)(params, x, y, cfg,
+                                                       forward=forward)
+        params, state, m = opt.update(grads, state, params)
+        return params, state, loss, m["grad_norm"]
+
+    # a batch larger than the train set would make har.batches yield nothing
+    it = har.batches(data, min(batch, len(data.y)), seed=seed)
+    t0 = time.time()
+    losses, step_s = [], []
+    for i in range(1, steps + 1):
+        bx, by = next(it)
+        t_step = time.perf_counter()
+        params, state, loss, _ = step(params, state, jnp.asarray(bx),
+                                      jnp.asarray(by))
+        losses.append(float(loss))            # blocks on the step
+        step_s.append(time.perf_counter() - t_step)
+        if test is not None and (i % 50 == 0 or i == 1):
+            n_eval = min(512, len(test.y))
+            acc = lstm.accuracy(params, jnp.asarray(test.x[:n_eval]),
+                                jnp.asarray(test.y[:n_eval]), cfg,
+                                forward=forward)
+            log(f"step {i:4d} loss {losses[-1]:.4f} "
+                f"test_acc {float(acc):.1%} "
+                f"({time.time() - t0:.0f}s)")
+    return params, losses, step_s
+
+
+def latency_protocol(fns: dict, cases: jax.Array
+                     ) -> tuple[dict[str, float], dict[str, jax.Array]]:
+    """Paper §4.1: classify each test case alone (batch 1), one after the
+    other.  ``fns`` maps a plan name to ``x -> logits``; returns the total
+    seconds per plan (warm-up call and compile excluded) and the logits of
+    every case."""
+    totals, logits = {}, {}
+    for name, fn in fns.items():
+        jax.block_until_ready(fn(cases[:1]))            # compile / warm up
+        outs = []
+        t0 = time.perf_counter()
+        for j in range(cases.shape[0]):
+            outs.append(jax.block_until_ready(fn(cases[j:j + 1])))
+        totals[name] = time.perf_counter() - t0
+        logits[name] = jnp.concatenate(outs)
+    return totals, logits
 
 
 def main() -> None:
@@ -53,37 +112,12 @@ def main() -> None:
     cfg = LSTMConfig().with_complexity(args.hidden, args.layers)
     print(f"config: {cfg.name} ({cfg.n_layers}L x {cfg.hidden}H) "
           f"plan={args.plan}")
-    train, test = har.make_har(args.n_train, args.n_test)
-    print(f"data: {len(train.y)} train / {len(test.y)} test windows "
+    train_data, test = har.make_har(args.n_train, args.n_test)
+    print(f"data: {len(train_data.y)} train / {len(test.y)} test windows "
           f"(UCI HAR protocol)")
 
-    params = lstm.init_params(jax.random.PRNGKey(0), cfg)
-    opt = AdamW(lr=warmup_cosine(args.lr, args.steps // 10, args.steps),
-                weight_decay=0.0)
-    state = opt.init(params)
-
-    @jax.jit
-    def step(params, state, x, y):
-        loss, grads = jax.value_and_grad(lstm.loss_fn)(params, x, y, cfg,
-                                                       forward=forward)
-        params, state, m = opt.update(grads, state, params)
-        return params, state, loss, m["grad_norm"]
-
-    # a batch larger than the train set would make har.batches yield nothing
-    it = har.batches(train, min(args.batch, len(train.y)), seed=0)
-    t0 = time.time()
-    n_eval = min(512, len(test.y))
-    for i in range(1, args.steps + 1):
-        bx, by = next(it)
-        params, state, loss, gn = step(params, state, jnp.asarray(bx),
-                                       jnp.asarray(by))
-        if i % 50 == 0 or i == 1:
-            acc = lstm.accuracy(params, jnp.asarray(test.x[:n_eval]),
-                                jnp.asarray(test.y[:n_eval]), cfg,
-                                forward=forward)
-            print(f"step {i:4d} loss {float(loss):.4f} "
-                  f"test_acc {float(acc):.1%} "
-                  f"({time.time() - t0:.0f}s)")
+    params, _, _ = train(cfg, forward, train_data, steps=args.steps,
+                         batch=args.batch, lr=args.lr, test=test)
 
     acc = lstm.accuracy(params, jnp.asarray(test.x), jnp.asarray(test.y),
                         cfg, forward=forward)
@@ -98,16 +132,15 @@ def main() -> None:
                                           replace=False)
     cases = jnp.asarray(test.x[idx])
     print(f"\nlatency for {n_cases} test cases (paper Fig 4 protocol):")
-    for name, fwd in lstm.FORWARD_PLANS.items():
-        fn = jax.jit(lambda p, x, fwd=fwd: fwd(p, x, cfg))
-        fn(params, cases[:1])  # compile
-        t0 = time.perf_counter()
-        for j in range(n_cases):
-            jax.block_until_ready(fn(params, cases[j:j + 1]))
-        dt = time.perf_counter() - t0
+    fns = {name: functools.partial(
+        jax.jit(lambda p, x, fwd=fwd: fwd(p, x, cfg)), params)
+        for name, fwd in lstm.FORWARD_PLANS.items()}
+    totals, _ = latency_protocol(fns, cases)
+    for name, dt in totals.items():
         print(f"  {name:12s} {dt * 1e3:8.1f} ms total "
               f"({dt * 1e3 / n_cases:.2f} ms/case)")
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -87,13 +87,25 @@ def best_cols_per_unit(dev: DeviceProfile, in_dim: int, out_dim: int,
 # Pallas BlockSpec chooser — the TPU instantiation of the same rule.
 # ---------------------------------------------------------------------------
 MXU_ALIGN = 128
-DEFAULT_VMEM_BUDGET = 96 << 20   # leave headroom below the 128MB v5e VMEM
+#: Scoped-VMEM limit every Pallas kernel is compiled with
+#: (kernels.compiler_params); below the 128 MiB of a v5e core.  Without it
+#: Mosaic applies its much smaller default scoped limit.
+VMEM_LIMIT_BYTES = 96 << 20
+#: Buffers the Pallas pipeline holds per BlockSpec'd operand: it fetches
+#: grid step i+1's block while step i computes, so every blocked input and
+#: output (the resident weight stack included) sits in VMEM twice, while
+#: the working-set tables count each block once.
+PIPELINE_BUFFERS = 2
+#: Working-set budget of the tiling choosers, keyed to the compile limit:
+#: the share of it one copy of every block may take.
+DEFAULT_VMEM_BUDGET = VMEM_LIMIT_BYTES // PIPELINE_BUFFERS
 #: Mobile-class fast-memory budget — the constrained tier MobiRNN targets.
 #: Small enough that the seed config's whole-T-resident fused-LSTM working
-#: set falls off it by T=512 (bwd) / T=2048 (fwd), so it is the shared
-#: stress budget for the time-streaming pipeline: benchmarks/run.py
-#: (STREAM_BUDGET rows + --stream-smoke / --quant-smoke, the CI
-#: invocations) and the acceptance tests (test_plan_equivalence,
+#: set (lane-padded terms) falls off it by T=256 (fwd) and does not fit it
+#: at any T in bwd, so it is the shared stress budget for the
+#: time-streaming pipeline: benchmarks/run.py (STREAM_BUDGET rows +
+#: --stream-smoke / --quant-smoke, the CI invocations) and the
+#: acceptance tests (test_plan_equivalence,
 #: test_scheduler_state) all reference THIS constant so they assert one
 #: viability surface.  Against it the int8-weight plan (fused_seq_q8,
 #: Q8_WEIGHT_BYTES per weight instead of 4) keeps whole-T residency deeper

@@ -43,6 +43,7 @@ The classifier head follows Guan & Ploetz-style HAR models: last hidden state
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable
 
 import jax
@@ -97,39 +98,46 @@ def forward_sequential(
     realising the paper's preallocation/reuse optimization.
     """
     p = _plain_params(params)
-    batch = x.shape[0]
-    c0, h0 = init_state(cfg, batch, x.dtype)
+    cells = [functools.partial(cell_fn, layer) for layer in p["layers"]]
+    return _scan_cells(cells, p["head"], x, cfg)
+
+
+def _scan_cells(cells: list[Callable], head: dict, x: jax.Array,
+                cfg: LSTMConfig) -> jax.Array:
+    """Run ``cells[i](inp, c, h) -> (c', h')`` layer by layer inside one
+    scan over time, then the classifier head on the last layer's h."""
+    c0, h0 = init_state(cfg, x.shape[0], x.dtype)
 
     def step(carry, x_t):
         c, h = carry
         inp = x_t
         cs, hs = [], []
-        for i in range(cfg.n_layers):
-            c_i, h_i = cell_fn(p["layers"][i], inp, c[i], h[i])
+        for i, cell in enumerate(cells):
+            c_i, h_i = cell(inp, c[i], h[i])
             cs.append(c_i)
             hs.append(h_i)
             inp = h_i
         return (jnp.stack(cs), jnp.stack(hs)), None
 
     (c, h), _ = jax.lax.scan(step, (c0, h0), jnp.swapaxes(x, 0, 1))
-    last = h[-1]
-    return last @ p["head"]["w"] + p["head"]["b"]
+    return h[-1] @ head["w"] + head["b"]
 
 
 def forward_fused_kernel(params: dict, x: jax.Array, cfg: LSTMConfig,
-                         interpret: bool = True) -> jax.Array:
-    """Sequential plan with the Pallas fused-cell kernel as the cell body."""
+                         interpret: bool | None = None) -> jax.Array:
+    """Sequential plan with the Pallas fused-cell kernel as the cell body;
+    each layer's weights are laid out for the kernel once per forward."""
     from repro.kernels import ops as kernel_ops
 
-    def cell_fn(p, inp, c, h):
-        return kernel_ops.lstm_cell(p["w"], p["b"], inp, c, h,
-                                    interpret=interpret)
-
-    return forward_sequential(params, x, cfg, cell_fn=cell_fn)
+    p = _plain_params(params)
+    cells = [kernel_ops.lstm_cell_step(layer["w"], layer["b"], x.shape[0],
+                                       interpret=interpret)
+             for layer in p["layers"]]
+    return _scan_cells(cells, p["head"], x, cfg)
 
 
 def forward_fused_seq(params: dict, x: jax.Array, cfg: LSTMConfig,
-                      interpret: bool = True,
+                      interpret: bool | None = None,
                       vmem_budget: int | None = None) -> jax.Array:
     """Sequence-resident plan: ONE Pallas dispatch for the whole (T x L)
     recurrence (kernels/lstm_seq.py) — dispatch count O(1) in T instead of
@@ -152,7 +160,8 @@ def forward_fused_seq(params: dict, x: jax.Array, cfg: LSTMConfig,
 
 
 def _forward_fused_seq_impl(params: dict, x: jax.Array, cfg: LSTMConfig, *,
-                            interpret: bool, vmem_budget: int | None,
+                            interpret: bool | None,
+                            vmem_budget: int | None,
                             quantized: bool) -> jax.Array:
     """Shared body of the f32 and int8 sequence-resident plans: stack the
     layer params, consult the (quantization-aware) ``(block_b, time_chunk)``
@@ -210,7 +219,7 @@ def _forward_fused_seq_impl(params: dict, x: jax.Array, cfg: LSTMConfig, *,
 
 
 def forward_fused_seq_q8(params: dict, x: jax.Array, cfg: LSTMConfig,
-                         interpret: bool = True,
+                         interpret: bool | None = None,
                          vmem_budget: int | None = None) -> jax.Array:
     """Int8-weight sequence-resident plan: the ``fused_seq`` fast path with
     the stacked weights quantized to per-output-channel symmetric int8

@@ -430,7 +430,7 @@ def _rwkv_chunked_xla(r, k, v, logw, u, state, *, chunk):
 
 
 def _rwkv_chunked_scan(r, k, v, logw, u, state, *, chunk, bh_tile=1,
-                       bwd=None, interpret=True):
+                       bwd=None, interpret=None):
     """kernels/wkv6 Pallas plan: model layout (B,S,H,*) folded to the
     kernel's (B*H, S, *), u broadcast per batch-head (its VJP sums the
     cotangent back over B), any T via the kernel's identity zero-pad."""
@@ -633,7 +633,7 @@ def _mamba_scan(x, dt, b, c, a, h0, *, chunk, block_b):
 
 
 def _mamba_fused_scan(x, dt, b, c, a, h0, *, chunk, block_b, bwd=None,
-                      interpret=True):
+                      interpret=None):
     """kernels/mamba_scan Pallas plan: ONE dispatch forward over a
     (batch-tile, time-chunk) grid with the f32 state carried in VMEM
     scratch, one reverse-sweep dispatch backward, any T and B via the

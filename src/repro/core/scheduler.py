@@ -206,13 +206,8 @@ class Scheduler:
 
     @staticmethod
     def _blocked_call(plan: Plan, args, kwargs):
-        out = plan.fn(*args, **kwargs)
-        try:  # block on async results
-            import jax
-            out = jax.block_until_ready(out)
-        except Exception:
-            pass
-        return out
+        import jax
+        return jax.block_until_ready(plan.fn(*args, **kwargs))
 
     def calibrate(self, *args, repeats: int = 3,
                   viable: Callable[[str], bool] | None = None,

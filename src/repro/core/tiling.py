@@ -39,6 +39,10 @@ from typing import Callable, Iterator, Protocol, runtime_checkable
 #: Streamed axes are double-buffered: one window computes while the next
 #: prefetches (kernels/lstm_seq._x_chunk_dma and the wkv6/mamba analogues).
 STREAM_SLOTS = 2
+#: TPU vreg lane count.  VMEM holds an array's minor axis in whole
+#: 128-lane tiles, and a manual DMA window must span whole tiles, so a
+#: working-set term costs its minor axis padded to ``lanes(width)``.
+LANES = 128
 
 
 @runtime_checkable
@@ -124,6 +128,11 @@ def pad_tiles(n: int, tile: int) -> int:
     """Length of an axis zero-padded up to the tile grid (manual-DMA
     kernels address tiles themselves, so the grid must divide exactly)."""
     return ceil_chunks(n, tile) * tile
+
+
+def lanes(width: int) -> int:
+    """A minor axis of ``width`` as VMEM holds it: whole ``LANES`` tiles."""
+    return pad_tiles(width, LANES)
 
 
 @dataclasses.dataclass

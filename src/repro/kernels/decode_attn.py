@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import compiler_params, resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -66,7 +68,8 @@ def _kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_scr, l_scr, acc_scr,
                    static_argnames=("block_s", "interpret", "scale"))
 def decode_attn(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                 lengths: jax.Array, *, scale: float | None = None,
-                block_s: int = 128, interpret: bool = True) -> jax.Array:
+                block_s: int = 128,
+                interpret: bool | None = None) -> jax.Array:
     """q: (B, Hq, dk); caches: (B, S, Hkv, dk); lengths: (B,) int32.
 
     Returns (B, Hq, dk) attention outputs for the single new token.
@@ -95,6 +98,7 @@ def decode_attn(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, dv), jnp.float32),
         ],
-        interpret=interpret,
+        compiler_params=compiler_params(),
+        interpret=resolve_interpret(interpret),
     )(q, k_cache, v_cache, len2)
     return out

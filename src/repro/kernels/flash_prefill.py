@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import compiler_params, resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -89,7 +91,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array, *,
                   window: int = 0, scale: float | None = None,
                   q_block: int = 128, k_block: int = 128,
-                  interpret: bool = True) -> jax.Array:
+                  interpret: bool | None = None) -> jax.Array:
     """q: (B, S, Hq, dh); k, v: (B, S, Hkv, dh).  Returns (B, S, Hq, dh).
 
     Causal; window > 0 additionally restricts attention to the last
@@ -125,6 +127,7 @@ def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((qb, 1), jnp.float32),
             pltpu.VMEM((qb, dh), jnp.float32),
         ],
-        interpret=interpret,
+        compiler_params=compiler_params(),
+        interpret=resolve_interpret(interpret),
     )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3)

@@ -31,7 +31,7 @@ core/lstm.py for the four-plan decision table.
 Time streaming: the recurrence is sequential in T, but the INPUT is not —
 so past a modest T the kernel does not need the whole ``(T, bm, P)`` block
 resident.  With ``time_chunk=tc`` the input stays in HBM
-(``pltpu.ANY``) and the kernel streams it through two ``(tc, bm, P)`` VMEM
+(``pl.ANY``) and the kernel streams it through two ``(tc, bm, P)`` VMEM
 buffers with async copies, prefetching chunk k+1 while chunk k computes
 (the classic double-buffer pipeline; pallas_guide §Double Buffering —
 exactly the remedy Lee et al. and Rezk et al. prescribe for RNN state on
@@ -39,7 +39,11 @@ constrained accelerators).  The trajectory-emitting forward additionally
 streams its ``(tc, L, bm, H)`` residual chunks OUT through two staging
 buffers, so VMEM residency is O(tc) — not O(T) — in every training-path
 dispatch while weights and the ``(c, h)`` carries stay resident across
-chunks.  Chunking changes data movement only: the per-step math is the
+chunks.  Every streamed array is lane-padded to a multiple of 128 first
+(``_pad_axis`` to ``tiling.lanes``): Mosaic refuses a DMA window whose
+last axis is narrower than the array's 128-lane tiling, which at the paper's H=32 is
+every window; the kernels read and write only the first P/H lanes.
+Chunking changes data movement only: the per-step math is the
 shared ``_step_layers`` body, so chunked and unchunked kernels are
 bit-identical (tests/test_lstm_seq.py asserts it).
 
@@ -70,6 +74,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import factorization, tiling
+from repro.kernels import compiler_params, resolve_interpret
 
 F32 = jnp.float32
 
@@ -164,6 +169,11 @@ def working_set_bytes(seq_len: int, n_layers: int, p_width: int, hidden: int,
     weight stack (defaults to ``dtype_bytes`` — pass it explicitly under
     mixed precision, e.g. bf16 activations over f32 parameters).
 
+    Every term is counted at its minor axis padded to whole lanes
+    (``tiling.lanes``), as VMEM holds it: at the paper's H=32 the (c, h)
+    blocks, trajectories and x/dx buffers take four times their logical
+    width.
+
     ``quantized=True`` sizes the int8-weight plan (``fused_seq_q8``): the
     weight stack is 1 byte/weight (unless ``w_dtype_bytes`` overrides), the
     f32 per-channel scales ride along with the f32 biases, PLUS one
@@ -176,26 +186,28 @@ def working_set_bytes(seq_len: int, n_layers: int, p_width: int, hidden: int,
     either way."""
     ws = tiling.WorkingSet(mode)
     wb = tiling.weight_dtype_bytes(dtype_bytes, w_dtype_bytes, quantized)
-    w_count = n_layers * (p_width + hidden) * 4 * hidden
-    b_count = n_layers * 4 * hidden
+    pw, hw, gw = (tiling.lanes(p_width), tiling.lanes(hidden),
+                  tiling.lanes(4 * hidden))
+    w_count = n_layers * (p_width + hidden) * gw
+    b_count = n_layers * gw
     weights = w_count * wb
     if quantized:
         biases = b_count * 4 * 2        # f32 bias + f32 per-channel scales
-        weights += (p_width + hidden) * 4 * hidden * 4   # dequant temporary
+        weights += (p_width + hidden) * gw * 4       # dequant temporary
     else:
         biases = b_count * wb
     ws.add("weights", weights).add("biases", biases)
     x_rows = tiling.streamed_rows(seq_len, time_chunk)
-    x_block = block_b * x_rows * p_width * dtype_bytes
+    x_block = block_b * x_rows * pw * dtype_bytes
     ws.add("x_block", x_block)
-    ws.add("state", 2 * n_layers * block_b * hidden * 4)     # f32 scratch
-    ws.add("outs", 2 * n_layers * block_b * hidden * dtype_bytes)
+    ws.add("state", 2 * n_layers * block_b * hw * 4)         # f32 scratch
+    ws.add("outs", 2 * n_layers * block_b * hw * dtype_bytes)
     if time_chunk is None:
         traj_rows = seq_len                                  # resident
     else:                                         # 2 slots x (tc+1)-row win
         traj_rows = tiling.STREAM_SLOTS * tiling.bwd_window_rows(
             seq_len, time_chunk)
-    ws.add("traj", 2 * traj_rows * n_layers * block_b * hidden * 4,
+    ws.add("traj", 2 * traj_rows * n_layers * block_b * hw * 4,
            bwd_only=True)
     ws.add("dw_scratch", (w_count + b_count) * 4, bwd_only=True)  # f32 accum
     if quantized:
@@ -205,7 +217,7 @@ def working_set_bytes(seq_len: int, n_layers: int, p_width: int, hidden: int,
     ws.add("dw_out", dw_out, bwd_only=True)
     ws.add("dx_block", x_block, bwd_only=True)   # dx mirrors x residency
     # (dc, dh) carries reuse `state`; the final-state cotangent blocks:
-    ws.add("cots", 2 * n_layers * block_b * hidden * dtype_bytes,
+    ws.add("cots", 2 * n_layers * block_b * hw * dtype_bytes,
            bwd_only=True)
     return ws.total()
 
@@ -381,7 +393,7 @@ def _seq_traj_q8_kernel(x_ref, w_ref, s_ref, b_ref, c_out_ref, h_out_ref,
 
 
 # ---------------------------------------------------------------------------
-# Time-chunked, double-buffered kernel bodies: x stays in HBM (pltpu.ANY)
+# Time-chunked, double-buffered kernel bodies: x stays in HBM (pl.ANY)
 # and streams through two (tc, bm, P) VMEM buffers; trajectory residuals
 # stream OUT through two staging buffers.  Helpers shared by fwd + traj.
 # ---------------------------------------------------------------------------
@@ -408,8 +420,9 @@ def _seq_chunked_kernel(x_hbm, w_ref, b_ref, c_out_ref, h_out_ref,
                         tc: int, nc: int, s_ref=None):
     """Forward with O(tc) input residency: same recurrence as ``_seq_kernel``
     but the (T, bm, P) block never materialises — chunk k+1 prefetches while
-    chunk k computes.  x_hbm: (T, Bp, P) in HBM (batch padded to the tile
-    grid); xbuf: (2, tc, bm, P) VMEM; weights and (c, h) stay resident.
+    chunk k computes.  x_hbm: (T, Bp, Pl) in HBM (batch padded to the tile
+    grid, lanes to ``tiling.LANES``); xbuf: (2, tc, bm, Pl) VMEM; weights
+    and (c, h) stay resident.
     """
     bm = c_scr.shape[1]
     ib = pl.program_id(0)
@@ -437,7 +450,7 @@ def _seq_chunked_kernel(x_hbm, w_ref, b_ref, c_out_ref, h_out_ref,
 
             @pl.when(t < seq_len)                        # tail-chunk guard
             def _advance():
-                inp = xbuf[slot, t - src].astype(F32)    # (bm, P)
+                inp = xbuf[slot, t - src][:, :p_width].astype(F32)
                 _step_layers(inp, w_ref, b_ref, c_scr, h_scr,
                              n_layers=n_layers, p_width=p_width,
                              s_ref=s_ref)
@@ -470,10 +483,11 @@ def _seq_traj_chunked_kernel(x_hbm, w_ref, b_ref, c_out_ref, h_out_ref,
                              tc: int, nc: int, s_ref=None):
     """Trajectory-emitting forward with O(tc) residency on BOTH sides: input
     chunks stream in, (tc, L, bm, H) trajectory chunks stream out through
-    two staging buffers each.  ct_hbm/ht_hbm are (nc*tc, L, Bp, H) in HBM —
-    time-padded so every chunk's output window is disjoint (the wrapper
-    slices [:T]); a staging slot is reused only after its previous flight
-    completes (the k-2 wait below).
+    two staging buffers each.  ct_hbm/ht_hbm are (nc*tc, L, Bp, Hl) in HBM —
+    time-padded so every chunk's output window is disjoint, lane-padded to
+    ``tiling.LANES`` (the wrapper slices [:T, :, :B, :H]); a staging slot
+    is reused only after its previous flight completes (the k-2 wait
+    below).
     """
     bm = c_scr.shape[1]
     ib = pl.program_id(0)
@@ -514,12 +528,13 @@ def _seq_traj_chunked_kernel(x_hbm, w_ref, b_ref, c_out_ref, h_out_ref,
 
             @pl.when(t < seq_len)                        # tail-chunk guard
             def _advance():
-                inp = xbuf[slot, t - src].astype(F32)    # (bm, P)
+                inp = xbuf[slot, t - src][:, :p_width].astype(F32)
                 _step_layers(inp, w_ref, b_ref, c_scr, h_scr,
                              n_layers=n_layers, p_width=p_width,
                              s_ref=s_ref)
-                ctb[slot, i] = c_scr[...]
-                htb[slot, i] = h_scr[...]
+                hidden = c_scr.shape[-1]
+                ctb[slot, i, :, :, :hidden] = c_scr[...]
+                htb[slot, i, :, :, :hidden] = h_scr[...]
             return c2
 
         jax.lax.fori_loop(0, tc, step, 0)
@@ -555,10 +570,12 @@ def _seq_traj_chunked_q8_kernel(x_hbm, w_ref, s_ref, b_ref, c_out_ref,
                              s_ref=s_ref)
 
 
-def _pad_batch(a: jax.Array, axis: int, padded: int) -> jax.Array:
-    """Zero-pad ``axis`` of ``a`` to length ``padded`` (manual-DMA kernels
-    address batch tiles themselves, so the tile grid must divide exactly —
-    garbage rows are masked/sliced, never computed into shared state)."""
+def _pad_axis(a: jax.Array, axis: int, padded: int) -> jax.Array:
+    """Zero-pad ``axis`` of ``a`` to length ``padded``.  Manual-DMA kernels
+    address batch tiles themselves, so the tile grid must divide exactly
+    (garbage rows are masked/sliced, never computed into shared state), and
+    their DMA windows must span whole 128-lane tiles (lanes pad to
+    ``tiling.lanes``)."""
     if a.shape[axis] == padded:
         return a
     pads = [(0, 0)] * a.ndim
@@ -569,7 +586,8 @@ def _pad_batch(a: jax.Array, axis: int, padded: int) -> jax.Array:
 @functools.partial(jax.jit,
                    static_argnames=("block_b", "time_chunk", "interpret"))
 def _lstm_seq_call(w: jax.Array, b: jax.Array, x: jax.Array,
-                   block_b: int, time_chunk: int | None, interpret: bool,
+                   block_b: int, time_chunk: int | None,
+                   interpret: bool | None,
                    scales: jax.Array | None = None
                    ) -> tuple[jax.Array, jax.Array]:
     L, H = w.shape[0], w.shape[-1] // 4
@@ -608,11 +626,13 @@ def _lstm_seq_call(w: jax.Array, b: jax.Array, x: jax.Array,
             pltpu.VMEM((L, bm, H), F32),
             pltpu.VMEM((L, bm, H), F32),
         ],
-        interpret=interpret,
+        compiler_params=compiler_params(),
+        interpret=resolve_interpret(interpret),
     )(xt, w, *s_in, b)
 
 
-def _lstm_seq_chunked_call(w, b, xt, bm: int, tc: int, interpret: bool,
+def _lstm_seq_chunked_call(w, b, xt, bm: int, tc: int,
+                           interpret: bool | None,
                            scales=None) -> tuple[jax.Array, jax.Array]:
     """Streamed forward: x lives in HBM, VMEM holds O(tc) of it."""
     L, H = w.shape[0], w.shape[-1] // 4
@@ -621,7 +641,8 @@ def _lstm_seq_chunked_call(w, b, xt, bm: int, tc: int, interpret: bool,
     n_tiles = pl.cdiv(B, bm)
     Bp = n_tiles * bm
     nc = pl.cdiv(T, tc)
-    xt = _pad_batch(xt, 1, Bp)
+    Pl = tiling.lanes(P)
+    xt = _pad_axis(_pad_axis(xt, 1, Bp), 2, Pl)
     out = jax.ShapeDtypeStruct((L, Bp, H), xt.dtype)
     if scales is None:
         kernel = functools.partial(_seq_chunked_kernel, n_layers=L,
@@ -636,7 +657,7 @@ def _lstm_seq_chunked_call(w, b, xt, bm: int, tc: int, interpret: bool,
         kernel,
         grid=(n_tiles,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),        # x streams manually
+            pl.BlockSpec(memory_space=pl.ANY),        # x streams manually
             pl.BlockSpec((L, P + H, 4 * H), lambda ib: (0, 0, 0)),
             *s_spec,
             pl.BlockSpec((L, 4 * H), lambda ib: (0, 0)),
@@ -647,12 +668,13 @@ def _lstm_seq_chunked_call(w, b, xt, bm: int, tc: int, interpret: bool,
         ],
         out_shape=[out, out],
         scratch_shapes=[
-            pltpu.VMEM((2, tc, bm, P), xt.dtype),        # double buffer
+            pltpu.VMEM((2, tc, bm, Pl), xt.dtype),       # double buffer
             pltpu.VMEM((L, bm, H), F32),
             pltpu.VMEM((L, bm, H), F32),
             pltpu.SemaphoreType.DMA((2,)),
         ],
-        interpret=interpret,
+        compiler_params=compiler_params(),
+        interpret=resolve_interpret(interpret),
     )(xt, w, *s_in, b)
     return c[:, :B], h[:, :B]
 
@@ -660,7 +682,7 @@ def _lstm_seq_chunked_call(w, b, xt, bm: int, tc: int, interpret: bool,
 @functools.partial(jax.jit,
                    static_argnames=("block_b", "time_chunk", "interpret"))
 def _lstm_seq_traj_call(w: jax.Array, b: jax.Array, x: jax.Array,
-                        block_b: int, interpret: bool,
+                        block_b: int, interpret: bool | None,
                         time_chunk: int | None = None,
                         scales: jax.Array | None = None
                         ) -> tuple[jax.Array, jax.Array, jax.Array,
@@ -708,11 +730,13 @@ def _lstm_seq_traj_call(w: jax.Array, b: jax.Array, x: jax.Array,
             pltpu.VMEM((L, bm, H), F32),
             pltpu.VMEM((L, bm, H), F32),
         ],
-        interpret=interpret,
+        compiler_params=compiler_params(),
+        interpret=resolve_interpret(interpret),
     )(xt, w, *s_in, b)
 
 
-def _lstm_seq_traj_chunked_call(w, b, xt, bm: int, tc: int, interpret: bool,
+def _lstm_seq_traj_chunked_call(w, b, xt, bm: int, tc: int,
+                                interpret: bool | None,
                                 scales=None
                                 ) -> tuple[jax.Array, jax.Array, jax.Array,
                                            jax.Array]:
@@ -724,9 +748,10 @@ def _lstm_seq_traj_chunked_call(w, b, xt, bm: int, tc: int, interpret: bool,
     Bp = n_tiles * bm
     nc = pl.cdiv(T, tc)
     Tp = nc * tc              # time-padded so chunk windows are disjoint
-    xt = _pad_batch(xt, 1, Bp)
+    Pl, Hl = tiling.lanes(P), tiling.lanes(H)
+    xt = _pad_axis(_pad_axis(xt, 1, Bp), 2, Pl)
     out = jax.ShapeDtypeStruct((L, Bp, H), xt.dtype)
-    traj = jax.ShapeDtypeStruct((Tp, L, Bp, H), F32)
+    traj = jax.ShapeDtypeStruct((Tp, L, Bp, Hl), F32)
     if scales is None:
         kernel = functools.partial(_seq_traj_chunked_kernel, n_layers=L,
                                    seq_len=T, p_width=P, tc=tc, nc=nc)
@@ -740,7 +765,7 @@ def _lstm_seq_traj_chunked_call(w, b, xt, bm: int, tc: int, interpret: bool,
         kernel,
         grid=(n_tiles,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),        # x streams manually
+            pl.BlockSpec(memory_space=pl.ANY),        # x streams manually
             pl.BlockSpec((L, P + H, 4 * H), lambda ib: (0, 0, 0)),
             *s_spec,
             pl.BlockSpec((L, 4 * H), lambda ib: (0, 0)),
@@ -748,23 +773,24 @@ def _lstm_seq_traj_chunked_call(w, b, xt, bm: int, tc: int, interpret: bool,
         out_specs=[
             pl.BlockSpec((L, bm, H), lambda ib: (0, ib, 0)),
             pl.BlockSpec((L, bm, H), lambda ib: (0, ib, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),        # traj streams out
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),        # traj streams out
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_shape=[out, out, traj, traj],
         scratch_shapes=[
-            pltpu.VMEM((2, tc, bm, P), xt.dtype),        # x double buffer
-            pltpu.VMEM((2, tc, L, bm, H), F32),          # c_traj staging
-            pltpu.VMEM((2, tc, L, bm, H), F32),          # h_traj staging
+            pltpu.VMEM((2, tc, bm, Pl), xt.dtype),       # x double buffer
+            pltpu.VMEM((2, tc, L, bm, Hl), F32),         # c_traj staging
+            pltpu.VMEM((2, tc, L, bm, Hl), F32),         # h_traj staging
             pltpu.VMEM((L, bm, H), F32),
             pltpu.VMEM((L, bm, H), F32),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
         ],
-        interpret=interpret,
+        compiler_params=compiler_params(),
+        interpret=resolve_interpret(interpret),
     )(xt, w, *s_in, b)
-    return c[:, :B], h[:, :B], ct[:T, :, :B], ht[:T, :, :B]
+    return c[:, :B], h[:, :B], ct[:T, :, :B, :H], ht[:T, :, :B, :H]
 
 
 # ---------------------------------------------------------------------------
@@ -903,7 +929,8 @@ def lstm_seq_q8(w: jax.Array, b: jax.Array, x: jax.Array, *,
                 block_b: int | None = None, time_chunk: int | None = None,
                 bwd_block_b: int | None = None,
                 bwd_time_chunk: int | None = None,
-                interpret: bool = True) -> tuple[jax.Array, jax.Array]:
+                interpret: bool | None = None
+                ) -> tuple[jax.Array, jax.Array]:
     """Whole-sequence stacked LSTM with int8-quantized weights, ONE dispatch.
 
     Same contract as ``lstm_seq`` (w is the f32 MASTER (L, P+H, 4H) stack;
@@ -935,7 +962,8 @@ def lstm_seq(w: jax.Array, b: jax.Array, x: jax.Array, *,
              block_b: int | None = None, time_chunk: int | None = None,
              bwd_block_b: int | None = None,
              bwd_time_chunk: int | None = None,
-             interpret: bool = True) -> tuple[jax.Array, jax.Array]:
+             interpret: bool | None = None
+             ) -> tuple[jax.Array, jax.Array]:
     """Whole-sequence stacked LSTM in ONE kernel dispatch.
 
     w: (L, P+H, 4H) stacked gate weights (stack_params); b: (L, 4H);

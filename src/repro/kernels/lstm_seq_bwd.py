@@ -47,6 +47,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import compiler_params, resolve_interpret
+
 F32 = jnp.float32
 
 
@@ -222,8 +224,10 @@ def _seq_bwd_chunked_kernel(x_hbm, w_ref, b_ref, ct_hbm, ht_hbm,
     chunk order (chunk k-1 prefetches while chunk k computes) and dx streams
     out through two staging buffers.
 
-    x_hbm: (T, Bp, P); ct_hbm/ht_hbm: (T, L, Bp, H) f32; dx_hbm:
-    (nc*tc, Bp, P) time-padded (wrapper slices [:T]).  The trajectory
+    x_hbm: (T, Bp, Pl); ct_hbm/ht_hbm: (T, L, Bp, Hl) f32; dx_hbm:
+    (nc*tc, Bp, Pl) time-padded (wrapper slices [:T, :B, :P]); every
+    streamed array is lane-padded to ``tiling.LANES`` and only its first
+    P/H lanes are read or written.  The trajectory
     window is ``tw = tc+1`` rows (tc when nc == 1) starting one row BEFORE
     the chunk so the pre-step state of the chunk's first timestep — the
     carry crossing the chunk boundary — comes from the same residuals the
@@ -307,19 +311,20 @@ def _seq_bwd_chunked_kernel(x_hbm, w_ref, b_ref, ct_hbm, ht_hbm,
 
             @pl.when(t < seq_len)                        # tail-chunk guard
             def _unwind():
-                x_t = mask2(xbuf[slot, t - xs].astype(F32))
-                c_t = mask3(ctb[slot, t - ts])           # (L, bm, H)
-                h_t = mask3(htb[slot, t - ts])
+                hidden = dc_scr.shape[-1]
+                x_t = mask2(xbuf[slot, t - xs][:, :p_width].astype(F32))
+                c_t = mask3(ctb[slot, t - ts][..., :hidden])   # (L, bm, H)
+                h_t = mask3(htb[slot, t - ts][..., :hidden])
                 lm1 = jnp.maximum(t - 1 - ts, 0)
                 alive = (t > 0).astype(F32)
-                c_prev_all = mask3(ctb[slot, lm1]) * alive
-                h_prev_all = mask3(htb[slot, lm1]) * alive
+                c_prev_all = mask3(ctb[slot, lm1][..., :hidden]) * alive
+                h_prev_all = mask3(htb[slot, lm1][..., :hidden]) * alive
                 dinp = _unwind_step(x_t, c_t, h_t, c_prev_all, h_prev_all,
                                     w_ref, b_ref, dw_scr, db_scr,
                                     dc_scr, dh_scr,
                                     n_layers=n_layers, p_width=p_width,
                                     s_ref=s_ref)
-                dxb[slot, t - k * tc] = dinp.astype(dxb.dtype)
+                dxb[slot, t - k * tc, :, :p_width] = dinp.astype(dxb.dtype)
             return c2
 
         jax.lax.fori_loop(0, tc, step, 0)
@@ -363,7 +368,7 @@ def _seq_bwd_chunked_q8_kernel(x_hbm, w_ref, s_ref, b_ref, ct_hbm, ht_hbm,
 @functools.partial(jax.jit,
                    static_argnames=("block_b", "time_chunk", "interpret"))
 def _lstm_seq_bwd_call(w, b, x, ct, ht, dc, dh, block_b: int,
-                       time_chunk: int | None, interpret: bool,
+                       time_chunk: int | None, interpret: bool | None,
                        scales=None):
     L, H = w.shape[0], w.shape[-1] // 4
     P = w.shape[1] - H
@@ -419,15 +424,17 @@ def _lstm_seq_bwd_call(w, b, x, ct, ht, dc, dh, block_b: int,
             pltpu.VMEM((L, bm, H), F32),                 # dc time-carry
             pltpu.VMEM((L, bm, H), F32),                 # dh time-carry
         ],
-        interpret=interpret,
+        compiler_params=compiler_params(),
+        interpret=resolve_interpret(interpret),
     )(xt, w, *s_in, b, ct, ht, dc, dh)
     return dw, db, jnp.swapaxes(dxt, 0, 1)               # dx: (B, T, P)
 
 
 def _lstm_seq_bwd_chunked_call(w, b, xt, ct, ht, dc, dh, bm: int, tc: int,
-                               interpret: bool, scales=None):
+                               interpret: bool | None, scales=None):
     """Streamed reverse sweep: x + trajectories in HBM, O(tc) VMEM."""
-    from repro.kernels.lstm_seq import _pad_batch
+    from repro.core import tiling
+    from repro.kernels.lstm_seq import _pad_axis
 
     L, H = w.shape[0], w.shape[-1] // 4
     P = w.shape[1] - H
@@ -437,11 +444,12 @@ def _lstm_seq_bwd_chunked_call(w, b, xt, ct, ht, dc, dh, bm: int, tc: int,
     nc = pl.cdiv(T, tc)
     Tp = nc * tc              # time-padded dx: chunk windows stay disjoint
     tw = tc + 1 if nc > 1 else tc
-    xt = _pad_batch(xt, 1, Bp)
-    ct = _pad_batch(ct, 2, Bp)
-    ht = _pad_batch(ht, 2, Bp)
-    dc = _pad_batch(dc, 1, Bp)
-    dh = _pad_batch(dh, 1, Bp)
+    Pl, Hl = tiling.lanes(P), tiling.lanes(H)
+    xt = _pad_axis(_pad_axis(xt, 1, Bp), 2, Pl)
+    ct = _pad_axis(_pad_axis(ct, 2, Bp), 3, Hl)
+    ht = _pad_axis(_pad_axis(ht, 2, Bp), 3, Hl)
+    dc = _pad_axis(dc, 1, Bp)
+    dh = _pad_axis(dh, 1, Bp)
     if scales is None:
         kernel = functools.partial(_seq_bwd_chunked_kernel, n_layers=L,
                                    seq_len=T, p_width=P, tc=tc, tw=tw,
@@ -459,12 +467,12 @@ def _lstm_seq_bwd_chunked_call(w, b, xt, ct, ht, dc, dh, bm: int, tc: int,
         kernel,
         grid=(n_tiles,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),        # x streams manually
+            pl.BlockSpec(memory_space=pl.ANY),        # x streams manually
             pl.BlockSpec((L, P + H, 4 * H), lambda ib: (0, 0, 0)),
             *s_spec,
             pl.BlockSpec((L, 4 * H), lambda ib: (0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),        # c_traj streams
-            pl.BlockSpec(memory_space=pltpu.ANY),        # h_traj streams
+            pl.BlockSpec(memory_space=pl.ANY),        # c_traj streams
+            pl.BlockSpec(memory_space=pl.ANY),        # h_traj streams
             pl.BlockSpec((L, bm, H), lambda ib: (0, ib, 0)),
             pl.BlockSpec((L, bm, H), lambda ib: (0, ib, 0)),
         ],
@@ -473,18 +481,18 @@ def _lstm_seq_bwd_chunked_call(w, b, xt, ct, ht, dc, dh, bm: int, tc: int,
             # written on the last batch tile (same contract as unchunked)
             pl.BlockSpec((L, P + H, 4 * H), lambda ib: (0, 0, 0)),
             pl.BlockSpec((L, 4 * H), lambda ib: (0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),        # dx streams out
+            pl.BlockSpec(memory_space=pl.ANY),        # dx streams out
         ],
         out_shape=[
             jax.ShapeDtypeStruct(w.shape, dw_dt),
             jax.ShapeDtypeStruct(b.shape, db_dt),
-            jax.ShapeDtypeStruct((Tp, Bp, P), xt.dtype),
+            jax.ShapeDtypeStruct((Tp, Bp, Pl), xt.dtype),
         ],
         scratch_shapes=[
-            pltpu.VMEM((2, tc, bm, P), xt.dtype),        # x double buffer
-            pltpu.VMEM((2, tw, L, bm, H), F32),          # c_traj window
-            pltpu.VMEM((2, tw, L, bm, H), F32),          # h_traj window
-            pltpu.VMEM((2, tc, bm, P), xt.dtype),        # dx staging
+            pltpu.VMEM((2, tc, bm, Pl), xt.dtype),       # x double buffer
+            pltpu.VMEM((2, tw, L, bm, Hl), F32),         # c_traj window
+            pltpu.VMEM((2, tw, L, bm, Hl), F32),         # h_traj window
+            pltpu.VMEM((2, tc, bm, Pl), xt.dtype),       # dx staging
             pltpu.VMEM(w.shape, F32),                    # dw accumulator
             pltpu.VMEM(b.shape, F32),                    # db accumulator
             pltpu.VMEM((L, bm, H), F32),                 # dc time-carry
@@ -494,13 +502,15 @@ def _lstm_seq_bwd_chunked_call(w, b, xt, ct, ht, dc, dh, bm: int, tc: int,
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
         ],
-        interpret=interpret,
+        compiler_params=compiler_params(),
+        interpret=resolve_interpret(interpret),
     )(xt, w, *s_in, b, ct, ht, dc, dh)
-    return dw, db, jnp.swapaxes(dxt[:T, :B], 0, 1)       # dx: (B, T, P)
+    return dw, db, jnp.swapaxes(dxt[:T, :B, :P], 0, 1)   # dx: (B, T, P)
 
 
 def lstm_seq_bwd(w, b, x, ct, ht, dc, dh, *, block_b: int,
-                 time_chunk: int | None = None, interpret: bool = True,
+                 time_chunk: int | None = None,
+                 interpret: bool | None = None,
                  scales=None):
     """Whole-sequence BPTT in ONE dispatch: (dw, db, dx).
 

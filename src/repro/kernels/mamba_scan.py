@@ -52,6 +52,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import factorization, tiling
+from repro.kernels import compiler_params, resolve_interpret
 
 F32 = jnp.float32
 
@@ -311,7 +312,8 @@ def _fwd_call(x, dt, b, c, a, h0, chunk, block_b, interpret, traj: bool):
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((bm, di, ds), jnp.float32)],
-        interpret=interpret,
+        compiler_params=compiler_params(),
+        interpret=resolve_interpret(interpret),
     )(x, dt, b, c, a, h0)
 
 
@@ -357,7 +359,8 @@ def _bwd_call(x, dt, b, c, a, h_traj, dy, dh_fin, h0_dtype, chunk, block_b,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((bm, di, ds), jnp.float32),
                         pltpu.VMEM((di, ds), jnp.float32)],
-        interpret=interpret,
+        compiler_params=compiler_params(),
+        interpret=resolve_interpret(interpret),
     )(x, dt, b, c, a, h_traj, dy, dh_fin)
 
 
@@ -399,7 +402,8 @@ _mamba.defvjp(_mamba_fwd, _mamba_bwd)
 def mamba_scan(x: jax.Array, dt: jax.Array, b: jax.Array, c: jax.Array,
                a: jax.Array, h0: jax.Array, *, chunk: int = 16,
                block_b: int | None = None, bwd: int = FUSED_BWD,
-               interpret: bool = True) -> tuple[jax.Array, jax.Array]:
+               interpret: bool | None = None
+               ) -> tuple[jax.Array, jax.Array]:
     """Fused selective scan over full sequences — ONE Pallas dispatch.
 
     x, dt: (B, T, di); b, c: (B, T, ds); a: (di, ds) (= -exp(a_log), f32);

@@ -1,11 +1,15 @@
 """Public jit'd entry points for the Pallas kernels.
 
 Block sizes are chosen by core/factorization.choose_block (the MobiRNN
-coarse-factorization rule) unless explicitly overridden.  On this CPU-only
-container `interpret=True` executes the kernel bodies in Python for
-correctness validation; on TPU pass `interpret=False`.
+coarse-factorization rule) unless explicitly overridden.  ``interpret=None``
+(the default everywhere) lets ``repro.kernels.resolve_interpret`` pick the
+mode from the backend: the Pallas interpreter on the CPU (the test suite),
+compiled Mosaic kernels on a TPU, where ``interpret=True`` is an error.
 """
 from __future__ import annotations
+
+import functools
+from typing import Callable
 
 import jax
 
@@ -15,22 +19,37 @@ from repro.kernels import lstm_cell as _lstm_cell
 from repro.kernels import wkv6 as _wkv6
 
 
-def lstm_cell(w: jax.Array, b: jax.Array, x: jax.Array, c: jax.Array,
-              h: jax.Array, *, interpret: bool = True,
-              block_b: int | None = None, block_h: int | None = None
-              ) -> tuple[jax.Array, jax.Array]:
-    B, H = c.shape
-    K = w.shape[0]
+def lstm_cell_step(w: jax.Array, b: jax.Array, batch: int, *,
+                   interpret: bool | None = None, block_b: int | None = None,
+                   block_h: int | None = None
+                   ) -> Callable[[jax.Array, jax.Array, jax.Array],
+                                 tuple[jax.Array, jax.Array]]:
+    """The fused cell as ``step(x, c, h) -> (c', h')`` with its blocks
+    chosen and the weights laid out for the kernel once — call it in a
+    time loop without re-laying the weights every step."""
+    K, H = w.shape[0], w.shape[1] // 4
     if block_b is None or block_h is None:
-        bm, bn, _ = factorization.choose_block(B, 4 * H, K)
+        bm, bn, _ = factorization.choose_block(batch, 4 * H, K)
         block_b = block_b or bm
         block_h = block_h or max(bn // 4, 1)
-    return _lstm_cell.lstm_cell(w, b, x, c, h, block_b=block_b,
-                                block_h=block_h, interpret=interpret)
+    w_t, b_t = _lstm_cell.gate_major(w, b, block_h)
+    return functools.partial(_lstm_cell.lstm_cell_tiled, w_t, b_t,
+                             block_b=block_b, block_h=block_h,
+                             interpret=interpret)
+
+
+def lstm_cell(w: jax.Array, b: jax.Array, x: jax.Array, c: jax.Array,
+              h: jax.Array, *, interpret: bool | None = None,
+              block_b: int | None = None, block_h: int | None = None
+              ) -> tuple[jax.Array, jax.Array]:
+    H = c.shape[-1]
+    assert w.shape == (x.shape[-1] + H, 4 * H), (w.shape, x.shape, H)
+    return lstm_cell_step(w, b, x.shape[0], interpret=interpret,
+                          block_b=block_b, block_h=block_h)(x, c, h)
 
 
 def lstm_seq(w: jax.Array, b: jax.Array, x: jax.Array, *,
-             interpret: bool = True, block_b: int | None = None,
+             interpret: bool | None = None, block_b: int | None = None,
              time_chunk: int | None = None,
              bwd_block_b: int | None = None,
              bwd_time_chunk: int | None = None
@@ -58,7 +77,7 @@ def lstm_seq(w: jax.Array, b: jax.Array, x: jax.Array, *,
 
 
 def lstm_seq_q8(w: jax.Array, b: jax.Array, x: jax.Array, *,
-                interpret: bool = True, block_b: int | None = None,
+                interpret: bool | None = None, block_b: int | None = None,
                 time_chunk: int | None = None,
                 bwd_block_b: int | None = None,
                 bwd_time_chunk: int | None = None
@@ -80,13 +99,13 @@ def lstm_seq_q8(w: jax.Array, b: jax.Array, x: jax.Array, *,
 
 
 def wkv6(r, k, v, logw, u, state, *, chunk: int = 32,
-         bwd: int = _wkv6.FUSED_BWD, interpret: bool = True):
+         bwd: int = _wkv6.FUSED_BWD, interpret: bool | None = None):
     return _wkv6.wkv6(r, k, v, logw, u, state, chunk=chunk, bwd=bwd,
                       interpret=interpret)
 
 
 def decode_attn(q, k_cache, v_cache, lengths, *, scale=None,
-                block_s: int = 128, interpret: bool = True):
+                block_s: int = 128, interpret: bool | None = None):
     return _decode_attn.decode_attn(q, k_cache, v_cache, lengths,
                                     scale=scale, block_s=block_s,
                                     interpret=interpret)
@@ -94,7 +113,7 @@ def decode_attn(q, k_cache, v_cache, lengths, *, scale=None,
 
 def flash_prefill(q, k, v, *, window: int = 0, scale=None,
                   q_block: int = 128, k_block: int = 128,
-                  interpret: bool = True):
+                  interpret: bool | None = None):
     from repro.kernels import flash_prefill as _fp
     return _fp.flash_prefill(q, k, v, window=window, scale=scale,
                              q_block=q_block, k_block=k_block,

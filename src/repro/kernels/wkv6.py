@@ -19,7 +19,7 @@ independent, so they tile freely — ``bh_tile`` rows share one grid step,
 their f32 states carried together in VMEM scratch (per-row math is
 statically unrolled, so results are bit-identical at ANY bh_tile).  The
 time axis STREAMS: the r/k/v/logw chunk windows live in HBM
-(``pltpu.ANY``) and the kernel moves them through two-slot double-buffered
+(``pl.ANY``) and the kernel moves them through two-slot double-buffered
 VMEM windows with async copies, prefetching chunk t+1 while chunk t
 computes (pallas_guide §Double Buffering — the same pipeline as
 kernels/lstm_seq's input streaming).  The backward streams the SAME windows
@@ -68,6 +68,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import factorization, tiling
+from repro.kernels import compiler_params, resolve_interpret
 
 F32 = jnp.float32
 
@@ -397,7 +398,7 @@ def _bwd_kernel(r_hbm, k_hbm, v_hbm, lw_hbm, u_ref, straj_hbm, do_hbm,
 # ---------------------------------------------------------------------------
 # pallas_call wrappers (T % chunk == 0, BH % bh_tile == 0 — the entry pads)
 # ---------------------------------------------------------------------------
-_ANY = functools.partial(pl.BlockSpec, memory_space=pltpu.ANY)
+_ANY = functools.partial(pl.BlockSpec, memory_space=pl.ANY)
 
 
 def _fwd_call(r, k, v, logw, u, state, chunk, bh_tile, interpret,
@@ -437,7 +438,8 @@ def _fwd_call(r, k, v, logw, u, state, chunk, bh_tile, interpret,
                         pltpu.VMEM((2, bt, chunk, dk), logw.dtype),
                         pltpu.VMEM((bt, dk, dv), jnp.float32),
                         pltpu.SemaphoreType.DMA((4, 2))],
-        interpret=interpret,
+        compiler_params=compiler_params(),
+        interpret=resolve_interpret(interpret),
     )(r, k, v, logw, u, state)
 
 
@@ -486,7 +488,8 @@ def _bwd_call(r, k, v, logw, u, s_traj, dout, ds_fin, s0_dtype, chunk,
                         pltpu.VMEM((bt, dk, dv), jnp.float32),
                         pltpu.VMEM((bt, dk), jnp.float32),
                         pltpu.SemaphoreType.DMA((6, 2))],
-        interpret=interpret,
+        compiler_params=compiler_params(),
+        interpret=resolve_interpret(interpret),
     )(r, k, v, logw, u, s_traj, dout, ds_fin)
 
 
@@ -540,7 +543,8 @@ _wkv6.defvjp(_wkv6_fwd, _wkv6_bwd)
 def wkv6(r: jax.Array, k: jax.Array, v: jax.Array, logw: jax.Array,
          u: jax.Array, state: jax.Array, *, chunk: int = 32,
          bh_tile: int = 1, bwd: int = FUSED_BWD,
-         interpret: bool = True) -> tuple[jax.Array, jax.Array]:
+         interpret: bool | None = None
+         ) -> tuple[jax.Array, jax.Array]:
     """Chunked RWKV6 scan over full sequences — ONE Pallas dispatch.
 
     r, k, logw: (BH, T, dk); v: (BH, T, dv); u: (BH, dk);

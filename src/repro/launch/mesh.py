@@ -15,6 +15,14 @@ import math
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
+
+
+def _auto(n_axes: int) -> tuple[AxisType, ...]:
+    """Auto axis types: the partitioner places what ``constrain`` leaves
+    open (jax.make_mesh defaults to Explicit axes, which refuse the
+    with_sharding_constraint calls the model code makes)."""
+    return (AxisType.Auto,) * n_axes
 
 
 def make_production_mesh(*, multi_pod: bool = False,
@@ -32,7 +40,8 @@ def make_production_mesh(*, multi_pod: bool = False,
             f"mesh {shape} needs {n} devices, found {len(devices)} — the "
             "dry-run must set XLA_FLAGS=--xla_force_host_platform_device_count"
             " before any jax import (see launch/dryrun.py)")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return jax.make_mesh(shape, axes, devices=devices[:n],
+                         axis_types=_auto(len(shape)))
 
 
 def make_host_mesh(model_axis: int = 1):
@@ -41,4 +50,5 @@ def make_host_mesh(model_axis: int = 1):
     n = len(devices)
     data = max(n // model_axis, 1)
     return jax.make_mesh((data, model_axis), ("data", "model"),
-                         devices=devices[: data * model_axis])
+                         devices=devices[: data * model_axis],
+                         axis_types=_auto(2))

@@ -19,7 +19,7 @@ import time
 import jax
 import numpy as np
 
-from repro import steps as steps_lib
+from repro import compile_cache, steps as steps_lib
 from repro.checkpoint import ckpt
 from repro.configs import get_arch
 from repro.data.lm import SyntheticLM
@@ -94,4 +94,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

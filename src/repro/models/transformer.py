@@ -81,20 +81,19 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
                                       bias=True),
         }
 
-    # blocks: tuple over period slots, leaves stacked over groups
+    # blocks: tuple over period slots, leaves stacked over groups.  One
+    # vmap over the groups' keys draws each slot's stack directly: no
+    # per-group copies to stack afterwards, and a jitted init compiles one
+    # slot's program instead of n_groups unrolled ones.
     slots = []
     block_keys = jax.random.split(k_blocks, n_groups * period
                                   ).reshape(n_groups, period, 2)
     for s in range(period):
-        per_group = [_init_slot(block_keys[g, s], cfg, s, dtype)
-                     for g in range(n_groups)]
-        stacked = jax.tree.map(
-            lambda *leaves: Annot(
-                jnp.stack([l.value for l in leaves]),
-                ("layers",) + tuple(leaves[0].axes)),
-            *per_group,
-            is_leaf=lambda x: isinstance(x, Annot))
-        slots.append(stacked)
+        init = functools.partial(_init_slot, cfg=cfg, slot=s, dtype=dtype)
+        _, axes = split(jax.eval_shape(init, block_keys[0, s]))
+        values = jax.vmap(lambda k: split(init(k))[0])(block_keys[:, s])
+        slots.append(jax.tree.map(
+            lambda v, ax: Annot(v, ("layers",) + tuple(ax)), values, axes))
     p["blocks"] = tuple(slots)
 
     p["final_norm"] = common.init_norm(cfg.d_model, cfg.norm, jnp.float32)

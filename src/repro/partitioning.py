@@ -20,28 +20,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 def shard_map(f: Callable, mesh: Mesh, in_specs: Any, out_specs: Any
               ) -> Callable:
-    """Version-compat shard_map with replication checking disabled.
-
-    jax >= 0.6 exposes ``jax.shard_map(..., check_vma=)``; this container's
-    jax still has ``jax.experimental.shard_map.shard_map(..., check_rep=)``.
-    All framework call sites (models/moe.py, models/rwkv.py) go through
-    here so the suite runs on both.
-    """
-    if hasattr(jax, "shard_map"):
-        fn, kw = jax.shard_map, "check_vma"
-    else:
-        from jax.experimental.shard_map import shard_map as fn
-        kw = "check_rep"
-    # the top-level promotion predates the check_rep->check_vma rename, so
-    # probe the signature instead of trusting the import location
-    import inspect
-    try:
-        if kw not in inspect.signature(fn).parameters:
-            kw = "check_rep" if kw == "check_vma" else "check_vma"
-    except (TypeError, ValueError):   # signature unavailable: keep default
-        pass
-    return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              **{kw: False})
+    """``jax.shard_map`` with replication checking off — the one spelling
+    the framework call sites (models/moe.py, models/rwkv.py) use."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 # ---------------------------------------------------------------------------
 # Logical axis names used throughout the framework.

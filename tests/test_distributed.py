@@ -87,6 +87,7 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_arch
 from repro.models import registry
 from repro.configs.base import ShapeConfig
+from repro.launch.mesh import make_host_mesh
 from repro.partitioning import split, make_rules, use_rules, tree_shardings
 cfg = get_arch('rwkv6-3b').reduced()
 m = registry.build(cfg)
@@ -94,7 +95,7 @@ params, axes = split(m.init(jax.random.PRNGKey(0)))
 batch = registry.make_batch(cfg, ShapeConfig('s', 32, 4, 'train'),
                             jax.random.PRNGKey(1))
 logits_1dev, _ = m.forward(params, batch)
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+mesh = make_host_mesh(model_axis=4)
 rules = make_rules(mesh)
 with mesh, use_rules(rules):
     logits_dist, _ = jax.jit(lambda p, b: m.forward(p, b))(params, batch)

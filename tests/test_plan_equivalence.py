@@ -244,7 +244,7 @@ def test_rwkv_oracle_bwd_fallback_keeps_single_forward():
 # fused_cell reroute, no oracle-VJP backward.
 # ---------------------------------------------------------------------------
 #: The mobile-class budget where the seed config's whole-T-resident working
-#: set falls off by T=512 (bwd) / T=2048 (fwd) while the chunked table
+#: set falls off by T=256 (fwd) / at every T (bwd) while the chunked table
 #: stays viable — same constant the CI smoke (benchmarks/run.py
 #: --stream-smoke) runs at.
 from repro.core.factorization import MOBILE_VMEM_BUDGET as _STREAM_BUDGET
@@ -406,7 +406,7 @@ def test_q8_budget_no_finer_than_f32_at_mobile_budget():
     cfg = LSTMConfig()
     p_width = max(cfg.input_dim, cfg.hidden)
     strictly_coarser = wholeT_won = False
-    for T in (32, 128, 512, 1024, 2048):
+    for T in (32, 128, 256, 512, 1024, 2048):
         for mode in ("fwd", "bwd"):
             f32 = seq_lib.choose_batch_block(
                 2, T, cfg.n_layers, p_width, cfg.hidden,

@@ -175,7 +175,7 @@ def test_plan_viability_long_T_streams_instead_of_filtering():
     from repro.core.factorization import MOBILE_VMEM_BUDGET
 
     cfg = MOBIRNN_LSTM
-    budget = MOBILE_VMEM_BUDGET   # whole-T bwd falls off it by T=512
+    budget = MOBILE_VMEM_BUDGET   # whole-T bwd never fits it
     for T in (128, 512, 2048, 8192):
         for train in (False, True):
             ok = lstm.plan_viability(cfg, 2, T, vmem_budget=budget,
