@@ -821,7 +821,7 @@ def bench_serving() -> None:
 
     # per-request latency distributions from the engine's always-on obs
     # metrics (accumulated over warmup + timed serves): TTFT is
-    # admit->first-token-on-host, TBT the per-lane gap between decode
+    # submit->first-token-on-host, TBT the per-lane gap between decode
     # tokens — the serving numbers MobiRNN-style tuning should move
     ttft = slot.metrics.histogram("serving/ttft_s").summary()
     tbt = slot.metrics.histogram("serving/tbt_s").summary()
@@ -1049,8 +1049,11 @@ def bench_obs_smoke(trace_path: str = "BENCH_ci_obs_trace.jsonl",
     assert len(admits) == len(news) and all(
         e["attrs"]["ttft_s"] > 0 for e in admits), "missing TTFT events"
     tick_ids = {e["span"] for e in ticks}
-    assert chooses and all(e["parent"] in tick_ids for e in chooses), \
-        "sched/choose not nested under serve/tick"
+    prepare = {e["span"]: e["parent"] for e in events
+               if e["name"] == "serve/tick/prepare"}
+    assert chooses and all(prepare.get(e["parent"]) in tick_ids
+                           for e in chooses), \
+        "sched/choose not nested under serve/tick/prepare"
     assert summaries and "serving/deadline_miss" in \
         summaries[-1]["attrs"]["counters"], "missing metrics summary"
     row("obs_smoke/trace", float(len(events)),

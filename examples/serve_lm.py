@@ -167,12 +167,14 @@ def main() -> None:
                   f"plans used: {plans}")
 
     # streaming: tokens surface per tick, not when the whole batch drains;
-    # TTFT is measured by the engine itself (admit -> first token on host)
-    # and surfaced both per request on Result.ttft_s and as a p50/p99
-    # histogram in the engine's always-on serving metrics
+    # TTFT is measured by the engine itself (submit -> first token on
+    # host, its queue part on Result.queue_s) and surfaced both per
+    # request on Result.ttft_s and as a p50/p99 histogram in the engine's
+    # always-on serving metrics
     results = slot.serve(reqs)
     for r in sorted(results, key=lambda r: r.ttft_s)[:3]:
         print(f"  uid={r.uid}: ttft={r.ttft_s * 1e3:.1f}ms "
+              f"queue={r.queue_s * 1e3:.1f}ms "
               f"decode={r.decode_s * 1e3:.1f}ms "
               f"tokens={r.tokens.shape[-1]}")
     ttft = slot.metrics.histogram("serving/ttft_s").summary()

@@ -13,9 +13,9 @@ sizes here, no interpolation surprises at p99 with small n.
 
 Serving instruments (pre-created by SlotEngine so snapshots always carry
 the full schema): counters serving/{ticks,tokens,retired,deadline_miss,
-quarantined,retries,shed}; histograms serving/ttft_s (admission -> first
-token host-visible — under chunked prefill this spans every interleaved
-chunk, the TTFT-under-contention number the adversary benchmarks bound),
+quarantined,retries,shed}; histograms serving/ttft_s (the engine taking
+the request -> first token handed to the client: the queue wait, then the
+prefill, which under chunked prefill spans every interleaved chunk),
 serving/tbt_s, and serving/prefill_chunk_s (per fixed-shape chunk
 dispatch; chunked mode only).
 """

@@ -16,6 +16,12 @@ Design constraints (ISSUE 7 tentpole):
 * **JSONL export.**  One JSON object per line; ``read_jsonl`` is the
   inverse.  Span records are emitted at span *exit* (so a child's record
   precedes its parent's) carrying ``ts`` (entry time) and ``dur_s``.
+* **The profiler's clock.**  While the tracer is enabled, every span
+  also enters a ``jax.profiler.TraceAnnotation`` of its bare name, so
+  under a running profile the program's spans sit on the host plane
+  beside the device's operations.  ``Tracer.interval`` records a span
+  that is already over (a request's life, which crosses generator
+  yields); it goes to the sink only.
 
 Record schema (see ROADMAP §Observability for the full event-name list —
 serving admission emits ``serve/admit`` per admitted request and, under
@@ -139,9 +145,18 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+def _annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` of ``name``; jax is imported
+    here, so this module stays importable without it."""
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation(name)
+
+
 class Span:
-    """Emitted as ONE record at exit; ``set`` adds attrs mid-flight."""
-    __slots__ = ("_tracer", "name", "attrs", "span_id", "parent_id", "_t0")
+    """Emitted as ONE record at exit; ``set`` adds attrs mid-flight.  Its
+    bare name is annotated on the profiler's host plane meanwhile."""
+    __slots__ = ("_tracer", "name", "attrs", "span_id", "parent_id", "_t0",
+                 "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self._tracer = tracer
@@ -158,12 +173,15 @@ class Span:
         tr = self._tracer
         self.parent_id = tr._stack[-1] if tr._stack else None
         tr._stack.append(self.span_id)
+        self._ann = _annotation(self.name)
+        self._ann.__enter__()
         self._t0 = tr.clock()
         return self
 
     def __exit__(self, *exc) -> bool:
         tr = self._tracer
         dur = tr.clock() - self._t0
+        self._ann.__exit__(None, None, None)
         if tr._stack and tr._stack[-1] == self.span_id:
             tr._stack.pop()
         tr._emit({
@@ -209,6 +227,17 @@ class Tracer:
         if not self.enabled:
             return NULL_SPAN
         return Span(self, name, attrs)
+
+    def interval(self, name: str, t0: float, t1: float, **attrs) -> None:
+        """Record a finished span from ``t0`` to ``t1`` on ``clock``.  It
+        has no parent and leaves the span stack alone: it is for work
+        that outlives the spans around it, such as a request's life."""
+        if not self.enabled:
+            return
+        self._emit({
+            "type": "span", "name": name, "span": self._new_id(),
+            "parent": None, "ts": t0, "dur_s": t1 - t0, "attrs": attrs,
+        })
 
     def flush(self) -> None:
         self.sink.flush()

@@ -272,9 +272,6 @@ class Engine(_EngineBase):
             t_decode = time.perf_counter() - t0
             wave_span.set(decode_s=t_decode)
         self.pool.give_back(cache)
-        self.metrics.counter("serving/waves").inc()
-        self.metrics.histogram("serving/wave_prefill_s").observe(t_prefill)
-        self.metrics.histogram("serving/wave_decode_s").observe(t_decode)
 
         # (B, [K,] max_new); toks[..., :0] covers an all-zero-budget wave
         gen = (np.stack(outs, axis=-1) if outs else toks[..., :0])
@@ -335,6 +332,9 @@ class SlotEngine(_EngineBase):
         # take_finished() — long-running submit()/stream() users must drain
         # it, or host memory grows with every retired request
         self.finished: dict[int, Result] = {}
+        # uid -> the moment the engine took the request, on the tracer's
+        # clock; dropped at its first token or when it ends without one
+        self._submitted: dict[int, float] = {}
 
         # -- chunked prefill (admission interleaving) -----------------------
         w = self.cfg.sliding_window or 0
@@ -499,10 +499,34 @@ class SlotEngine(_EngineBase):
             self.metrics.counter("serving/deadline_miss").inc()
             self._terminal(req, FinishReason.DEADLINE)
             return False
+        self._stamp(req)
         return True
+
+    def _stamp(self, req: Request) -> None:
+        """Note when the engine took ``req`` (its TTFT starts here); a
+        retry that re-queues a request keeps its first stamp."""
+        self._submitted.setdefault(req.uid, trace_lib.get_tracer().clock())
+
+    def _first_token(self, req: Request, t_start: float, prompt_len: int,
+                     chunks: int) -> tuple[float, float]:
+        """Close ``req``'s stamp now, as its first token is handed to the
+        client after a prefill that started at ``t_start`` (tracer's
+        clock).  Returns (ttft_s, queue_s) and records ``serve/request``."""
+        tracer = trace_lib.get_tracer()
+        t_first = tracer.clock()
+        t_sub = self._submitted.pop(req.uid, t_start)
+        ttft_s, queue_s = t_first - t_sub, t_start - t_sub
+        self.metrics.histogram("serving/ttft_s").observe(ttft_s)
+        if tracer.enabled:
+            tracer.interval("serve/request", t_sub, t_first, uid=req.uid,
+                            queue_s=queue_s, lane_s=t_first - t_start,
+                            prompt_len=prompt_len, chunks=chunks)
+        return ttft_s, queue_s
 
     def _admit_one(self, index: int, req: Request) -> TokenEvent:
         prompt = np.asarray(req.prompt, np.int32)
+        tracer = trace_lib.get_tracer()
+        t_start = tracer.clock()
         t0 = time.perf_counter()
         tok0, self._scratch = self._prefill_sample(
             self.params, self._scratch,
@@ -510,15 +534,13 @@ class SlotEngine(_EngineBase):
         tok0 = tok0[0]                       # () or (K,), device array
         prefill_s = time.perf_counter() - t0
         tok0_np = np.asarray(tok0, np.int32)  # blocks: token host-visible
-        ttft_s = time.perf_counter() - t0     # admit -> first token
-        self.manager.admit(index, req, self._scratch, tok0, prefill_s,
-                           ttft_s=ttft_s)
-        self.metrics.histogram("serving/ttft_s").observe(ttft_s)
-        tracer = trace_lib.get_tracer()
+        slot = self.manager.admit(index, req, self._scratch, tok0, prefill_s)
+        slot.ttft_s, slot.queue_s = self._first_token(
+            req, t_start, int(prompt.shape[-1]), chunks=1)
         if tracer.enabled:
             tracer.event("serve/admit", uid=req.uid, slot=index,
                          prompt_len=int(prompt.shape[-1]),
-                         prefill_s=prefill_s, ttft_s=ttft_s)
+                         prefill_s=prefill_s, ttft_s=slot.ttft_s)
         return TokenEvent(req.uid, tok0_np, 0,
                           done=(req.max_new_tokens <= 1))
 
@@ -528,9 +550,18 @@ class SlotEngine(_EngineBase):
         arrival deadline, shed, failure out of retries) and return its
         stream event."""
         self._attempts.pop(req.uid, None)
+        self._submitted.pop(req.uid, None)
         self.finished[req.uid] = Result(req.uid, self.manager.empty_tokens(),
                                         0.0, 0.0, [], finish_reason=reason)
         return TokenEvent(req.uid, None, 0, done=True, finish_reason=reason)
+
+    def _zero_budget(self, req: Request) -> TokenEvent:
+        """Complete a request with no tokens owed, without a lane."""
+        self._submitted.pop(req.uid, None)
+        self.finished[req.uid] = Result(
+            req.uid, self.manager.empty_tokens(), 0.0, 0.0, [])
+        return TokenEvent(req.uid, None, 0, done=True,
+                          finish_reason=FinishReason.LENGTH)
 
     def _finish(self, res: Result) -> None:
         """Adopt a retired lane's Result — the one place lane retirement
@@ -624,12 +655,21 @@ class SlotEngine(_EngineBase):
             toks = lane.prompt[..., lane.filled:lane.filled + seg]
             first = (self._first_true if lane.chunks_done == 0
                      else self._first_false)
+            on = tracer.enabled
+            null = trace_lib.NULL_SPAN
             t0 = time.perf_counter()
-            tok, lane.cache = self._prefill_chunk(
-                self.params, lane.cache,
-                self._prefill_batch(toks.reshape((1,) + toks.shape)), first)
-            tok = jax.block_until_ready(tok)
-            chunk_s = time.perf_counter() - t0
+            with (tracer.span("serve/chunk", uid=req.uid,
+                              chunk=lane.chunks_done, seg_len=seg)
+                  if on else null):
+                batch = self._prefill_batch(toks.reshape((1,) + toks.shape))
+                with tracer.span("serve/chunk/dispatch") if on else null:
+                    tok, lane.cache = self._prefill_chunk(
+                        self.params, lane.cache, batch, first)
+                with tracer.span("serve/chunk/sync") if on else null:
+                    tok = jax.block_until_ready(tok)
+                chunk_s = time.perf_counter() - t0
+                # the chunk's last device work, so inside its span
+                last_tok = tok[0]              # () or (K,), device array
         except Exception as err:      # containment: never escapes
             self._lanes.remove(lane)
             yield from self._lane_failed(lane, now, err)
@@ -638,7 +678,7 @@ class SlotEngine(_EngineBase):
         lane.filled += seg
         lane.chunks_done += 1
         lane.prefill_s += chunk_s
-        lane.last_tok = tok[0]               # () or (K,), device array
+        lane.last_tok = last_tok
         self.metrics.histogram("serving/prefill_chunk_s").observe(chunk_s)
         if tracer.enabled:
             tracer.event("serve/prefill_chunk", uid=req.uid,
@@ -650,15 +690,15 @@ class SlotEngine(_EngineBase):
         self._lanes.remove(lane)
         idx = mgr.free_indices()[0]
         tok0_np = np.asarray(lane.last_tok, np.int32)
-        ttft_s = time.perf_counter() - lane.t_start
-        mgr.admit(idx, req, lane.cache, lane.last_tok, lane.prefill_s,
-                  ttft_s=ttft_s)
+        slot = mgr.admit(idx, req, lane.cache, lane.last_tok, lane.prefill_s)
         self._scratch_pool.give_back(lane.cache)
-        self.metrics.histogram("serving/ttft_s").observe(ttft_s)
+        slot.ttft_s, slot.queue_s = self._first_token(
+            req, lane.t_start, int(lane.prompt.shape[-1]),
+            chunks=lane.chunks_done)
         if tracer.enabled:
             tracer.event("serve/admit", uid=req.uid, slot=idx,
                          prompt_len=int(lane.prompt.shape[-1]),
-                         prefill_s=lane.prefill_s, ttft_s=ttft_s,
+                         prefill_s=lane.prefill_s, ttft_s=slot.ttft_s,
                          chunks=lane.chunks_done)
         ev = TokenEvent(req.uid, tok0_np, 0, done=(req.max_new_tokens <= 1))
         yield ev
@@ -695,10 +735,7 @@ class SlotEngine(_EngineBase):
                 break
             if req.max_new_tokens <= 0:
                 # zero-budget request: complete without touching a lane
-                self.finished[req.uid] = Result(
-                    req.uid, mgr.empty_tokens(), 0.0, 0.0, [])
-                yield TokenEvent(req.uid, None, 0, done=True,
-                                 finish_reason=FinishReason.LENGTH)
+                yield self._zero_budget(req)
                 continue
             prompt = np.asarray(req.prompt, np.int32)
             if prompt.shape[-1] > self._chunk_safe_len:
@@ -722,7 +759,7 @@ class SlotEngine(_EngineBase):
             self._lanes.append(PrefillLane(
                 request=req, cache=self._scratch_pool.checkout(),
                 schedule=chunk_schedule(prompt.shape[-1], self._chunk_len),
-                prompt=prompt, t_start=time.perf_counter()))
+                prompt=prompt, t_start=tracer.clock()))
             if tracer.enabled:
                 tracer.event("serve/prefill_start", uid=req.uid,
                              prompt_len=int(prompt.shape[-1]),
@@ -772,6 +809,7 @@ class SlotEngine(_EngineBase):
         """
         for req in requests or []:
             self._validate(req)          # fail fast, not mid-stream
+            self._stamp(req)
         pending = collections.deque(requests or [])
         mgr = self.manager
         metrics = self.metrics
@@ -793,7 +831,9 @@ class SlotEngine(_EngineBase):
                         tracer.event("serve/fault", kind="flood", tick=tick,
                                      uid=req.uid)
                     try:
-                        if not self.queue.submit(req, now=now):
+                        if self.queue.submit(req, now=now):
+                            self._stamp(req)
+                        else:
                             metrics.counter("serving/deadline_miss").inc()
                             yield self._terminal(req, FinishReason.DEADLINE)
                     except QueueFull:
@@ -807,7 +847,9 @@ class SlotEngine(_EngineBase):
                 for ready_t, req in self._retry_backlog:
                     if ready_t > now or self.queue.full:
                         still.append((ready_t, req))
-                    elif not self.queue.submit(req, now=now):
+                    elif self.queue.submit(req, now=now):
+                        self._stamp(req)
+                    else:
                         metrics.counter("serving/deadline_miss").inc()
                         yield self._terminal(req, FinishReason.DEADLINE)
                 self._retry_backlog = still
@@ -865,10 +907,7 @@ class SlotEngine(_EngineBase):
                         break
                     if req.max_new_tokens <= 0:
                         # zero-budget request: complete without a lane
-                        self.finished[req.uid] = Result(
-                            req.uid, mgr.empty_tokens(), 0.0, 0.0, [])
-                        yield TokenEvent(req.uid, None, 0, done=True,
-                                         finish_reason=FinishReason.LENGTH)
+                        yield self._zero_budget(req)
                         continue
                     try:
                         if (inj is not None
@@ -891,7 +930,8 @@ class SlotEngine(_EngineBase):
             metrics.gauge("serving/queue_depth").set(float(queue_depth))
             metrics.gauge("serving/occupancy").set(occupied / mgr.n_slots)
 
-            if not mgr.active_mask().any():
+            n_active = int(mgr.active_mask().sum())
+            if not n_active:
                 if (pending or len(self.queue) or self._retry_backlog
                         or self._lanes):
                     # only expiries/zero-token admissions/backoffs/partial
@@ -900,36 +940,43 @@ class SlotEngine(_EngineBase):
                     continue
                 break
 
-            # ONE fused masked decode tick across all lanes — the span
-            # wraps choose + dispatch + host copy, so the per-tick
-            # sched/choose event nests under serve/tick in the trace
+            # ONE fused masked decode tick across all lanes, in three
+            # spans: prepare (choose — its sched/choose event nests here —
+            # batch, poison mask), the asynchronous dispatch, and the
+            # blocking host copy of its outputs
+            on = tracer.enabled
+            null = trace_lib.NULL_SPAN
             span = (tracer.span("serve/tick", tick=tick,
-                                queue_depth=queue_depth, occupied=occupied)
-                    if tracer.enabled else trace_lib.NULL_SPAN)
+                                queue_depth=queue_depth, occupied=occupied,
+                                active=n_active)
+                    if on else null)
             with span:
-                d = self.scheduler.choose()
-                plan = self.scheduler.plans[d.plan]
-                batch = mgr.tick_batch()
-                lanes = inj.poison_lanes(tick) if inj is not None else ()
-                if lanes:
-                    mask = np.zeros((self.n_slots,), bool)
-                    mask[list(lanes)] = True
-                    batch["poison"] = jnp.asarray(mask)
-                    if tracer.enabled:
-                        for lane in lanes:
-                            tracer.event("serve/fault", kind="poison",
-                                         tick=tick, lane=lane)
-                else:
-                    batch["poison"] = self._no_poison
+                with tracer.span("serve/tick/prepare") if on else null:
+                    d = self.scheduler.choose()
+                    plan = self.scheduler.plans[d.plan]
+                    batch = mgr.tick_batch()
+                    lanes = inj.poison_lanes(tick) if inj is not None else ()
+                    if lanes:
+                        mask = np.zeros((self.n_slots,), bool)
+                        mask[list(lanes)] = True
+                        batch["poison"] = jnp.asarray(mask)
+                        if on:
+                            for lane in lanes:
+                                tracer.event("serve/fault", kind="poison",
+                                             tick=tick, lane=lane)
+                    else:
+                        batch["poison"] = self._no_poison
                 t0 = time.perf_counter()
-                sampled_dev, lane_ok_dev, mgr.cache = plan.fn(
-                    self.params, mgr.cache, batch)
+                with tracer.span("serve/tick/dispatch") if on else null:
+                    sampled_dev, lane_ok_dev, mgr.cache = plan.fn(
+                        self.params, mgr.cache, batch)
                 mgr.set_sampled(sampled_dev)
-                sampled = np.asarray(sampled_dev)  # blocks; 1 copy per tick
-                lane_ok = np.asarray(lane_ok_dev)
+                with tracer.span("serve/tick/sync") if on else null:
+                    sampled = np.asarray(sampled_dev)  # blocks; 1 per tick
+                    lane_ok = np.asarray(lane_ok_dev)
                 tick_s = time.perf_counter() - t0
                 extra_s = inj.slow_s(tick) if inj is not None else 0.0
-                if extra_s and tracer.enabled:
+                if extra_s and on:
                     tracer.event("serve/fault", kind="slow", tick=tick,
                                  extra_s=extra_s)
                 observed_s = tick_s + extra_s

@@ -97,10 +97,14 @@ class Result:
     decode_s: float
     plan_decisions: list[str]
     finish_reason: str = FinishReason.LENGTH   # one of FINISH_REASONS
-    #: admission -> first sampled token available on host, seconds.
-    #: 0.0 for requests that never reached a lane (queue expiry,
-    #: zero-token budgets) — mirrors prefill_s there.
+    #: the engine taking the request (``submit``, or ``stream(requests)``)
+    #: -> its first sampled token handed to the client, seconds: the
+    #: queue wait, then the prefill.  0.0 for requests that never reached
+    #: a lane (queue expiry, zero-token budgets) — mirrors prefill_s there.
     ttft_s: float = 0.0
+    #: the engine taking the request -> its prefill starting, seconds;
+    #: ``ttft_s - queue_s`` is prefill start (admission) -> first token
+    queue_s: float = 0.0
 
     def __post_init__(self) -> None:
         if self.finish_reason not in FINISH_REASONS:
@@ -161,7 +165,7 @@ class PrefillLane:
     prompt: np.ndarray = None       # int32 view of request.prompt
     filled: int = 0                 # prompt tokens already prefilled
     chunks_done: int = 0
-    t_start: float = 0.0            # perf_counter at lane start (TTFT)
+    t_start: float = 0.0            # tracer's clock at prefill start
     prefill_s: float = 0.0          # accumulated chunk dispatch time
     last_tok: Any = None            # device token from the latest chunk
 
@@ -244,7 +248,8 @@ class Slot:
     tokens: list = dataclasses.field(default_factory=list)
     prefill_s: float = 0.0
     admitted_t: float = 0.0
-    ttft_s: float = 0.0              # admit -> first token, host-visible
+    ttft_s: float = 0.0              # submit -> first token handed out
+    queue_s: float = 0.0             # submit -> prefill start
     last_token_t: float = 0.0        # perf_counter of the latest token (TBT)
     plan_decisions: list = dataclasses.field(default_factory=list)
 
@@ -310,17 +315,15 @@ class SlotManager:
 
     # -- lane lifecycle -------------------------------------------------
     def admit(self, index: int, req: Request, lane_cache: Any,
-              first_token: Any, prefill_s: float,
-              ttft_s: float | None = None) -> Slot:
+              first_token: Any, prefill_s: float) -> Slot:
         """Left-pack a freshly prefilled request into a free lane.
 
         ``lane_cache`` is the B=1 scratch cache holding the prompt's state
         (scalar ``pos`` = prompt length); its single lane is scattered into
         lane ``index`` through the donated admit jit, together with the
         prompt's first sampled token (``first_token``, device array).
-        ``ttft_s`` is the admit->first-token wall time the engine measured
-        (the first token IS produced at admission); defaults to
-        ``prefill_s`` for callers that do not separate the two."""
+        The engine sets the slot's ``ttft_s`` and ``queue_s`` as it hands
+        that token to the client."""
         s = self.slots[index]
         assert not s.occupied, index
         self.cache, self.tokens, self.active = self._admit(
@@ -331,7 +334,6 @@ class SlotManager:
         s.remaining = req.max_new_tokens - 1
         s.prefill_s = prefill_s
         s.admitted_t = time.perf_counter()
-        s.ttft_s = prefill_s if ttft_s is None else ttft_s
         s.last_token_t = s.admitted_t
         s.plan_decisions = []
         return s
@@ -349,7 +351,8 @@ class SlotManager:
         res = Result(uid=s.request.uid, tokens=toks, prefill_s=s.prefill_s,
                      decode_s=time.perf_counter() - s.admitted_t,
                      plan_decisions=s.plan_decisions,
-                     finish_reason=finish_reason, ttft_s=s.ttft_s)
+                     finish_reason=finish_reason, ttft_s=s.ttft_s,
+                     queue_s=s.queue_s)
         self.slots[index] = Slot(index)
         return res
 

@@ -5,6 +5,7 @@ The traced-SlotEngine integration checks (token identity, per-tick spans,
 zero-alloc with tracing on) live in tests/test_serving_slots.py next to
 the serving fixtures; this module owns the unit surface.
 """
+import contextlib
 import dataclasses
 import math
 
@@ -93,6 +94,77 @@ def test_configure_installs_and_rejects_both(tmp_path):
         tr.close()
     finally:
         set_tracer(old)
+
+
+def test_spans_land_on_the_profilers_host_plane(tmp_path):
+    """Under a running profile every span is a host-plane event of its
+    bare name, on the profile's clock: inside the window annotation and
+    nested as the tracer nested it.  Events stay in the sink."""
+    import glob
+
+    from jax.profiler import ProfileData, TraceAnnotation
+
+    sink = ListSink()
+    tr = Tracer(sink)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with TraceAnnotation("bench/trace_window"):
+            with tr.span("serve/tick", tick=3):
+                with tr.span("serve/tick/dispatch", plan="p"):
+                    jnp.ones(4).block_until_ready()
+                tr.event("serve/fault", kind="slow")
+    finally:
+        jax.profiler.stop_trace()
+    [path] = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                           "*.xplane.pb"))
+    seen = {e.name: (e.start_ns, e.start_ns + e.duration_ns)
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events}
+    lo, hi = seen["bench/trace_window"]
+    t0, t1 = seen["serve/tick"]
+    d0, d1 = seen["serve/tick/dispatch"]
+    assert lo <= t0 <= d0 <= d1 <= t1 <= hi
+    assert "serve/fault" not in seen
+    # the sink's records keep their schema
+    assert [r["name"] for r in sink.records] == [
+        "serve/tick/dispatch", "serve/fault", "serve/tick"]
+    assert sink.records[-1]["attrs"] == {"tick": 3}
+
+
+def test_interval_records_explicit_times_off_the_stack():
+    sink = ListSink()
+    tr = Tracer(sink)
+    with tr.span("outer") as outer:
+        tr.interval("serve/request", 1.5, 4.0, uid=7, queue_s=1.0)
+        assert tr._stack == [outer.span_id]       # the stack is untouched
+        tr.event("after")
+    interval, after, outer_r = sink.records
+    assert interval == {"type": "span", "name": "serve/request",
+                        "span": interval["span"], "parent": None,
+                        "ts": 1.5, "dur_s": 2.5, "seq": 0,
+                        "attrs": {"uid": 7, "queue_s": 1.0}}
+    assert interval["span"] != outer_r["span"]
+    assert after["parent"] == outer_r["span"]
+    off = Tracer()
+    off.interval("serve/request", 0.0, 1.0, uid=1)    # disabled: no-op
+    assert off._seq == 0
+
+
+def test_disabled_tracer_builds_no_annotation(monkeypatch):
+    """Tracing off, a span is the shared no-op: no record, no profiler
+    annotation.  Tracing on, each span enters one."""
+    built = []
+    monkeypatch.setattr(trace_lib, "_annotation",
+                        lambda name: built.append(name) or
+                        contextlib.nullcontext())
+    off = Tracer()
+    with off.span("serve/tick"):
+        pass
+    assert built == []
+    with Tracer(ListSink()).span("serve/tick"):
+        pass
+    assert built == ["serve/tick"]
 
 
 # ---------------------------------------------------------------------------

@@ -230,6 +230,9 @@ def test_prefill_fault_with_budget_retries_to_length(tiny, baseline):
         np.testing.assert_array_equal(r.tokens, baseline[r.uid])
     assert engine.metrics.counter("serving/retries").value == 1
     assert engine._scratch_pool.stats.buffers_built == 1
+    # the retry keeps the request's first stamp: its TTFT and queue time
+    # include the failed attempt
+    assert 0.0 < results[0].queue_s < results[0].ttft_s
 
 
 # ---------------------------------------------------------------------------
@@ -362,6 +365,8 @@ def _chaos_property(tiny, baseline, seed):
             np.testing.assert_array_equal(res.tokens, baseline[req.uid])
     assert engine.pool.stats.buffers_built == 1
     assert engine._scratch_pool.stats.buffers_built == 1
+    # every TTFT stamp closed: at a first token or a tokenless end
+    assert not engine._submitted
 
 
 @pytest.mark.parametrize("seed", [0, 3])

@@ -193,8 +193,12 @@ def test_slot_engine_traced_run_token_identical(tiny):
         assert t["attrs"]["tick_s"] > 0
     for a in admits:
         assert a["attrs"]["ttft_s"] > 0
-    # every per-tick plan decision nests under its tick span
-    assert chooses and all(c["parent"] in tick_ids for c in chooses)
+    # every per-tick plan decision nests under its tick span, inside the
+    # tick's prepare phase
+    prepare = {r["span"]: r["parent"] for r in sink.records
+               if r["name"] == "serve/tick/prepare"}
+    assert chooses and all(prepare.get(c["parent"]) in tick_ids
+                           for c in chooses)
     # the run closes with a metrics summary event
     summaries = [r for r in sink.records if r["name"] == "serve/metrics"]
     assert summaries
@@ -205,16 +209,20 @@ def test_slot_engine_traced_run_token_identical(tiny):
 
 
 def test_slot_engine_ttft_on_results(tiny):
-    """Satellite: per-request TTFT (admit -> first token on host) rides on
-    Result next to decode_s, and feeds the serving/ttft_s histogram."""
+    """Satellite: per-request TTFT (submit -> first token on host) rides on
+    Result next to decode_s with its queue part, and feeds the
+    serving/ttft_s histogram."""
     cfg, model, params = tiny
     engine = SlotEngine(model, params, n_slots=2, max_seq=64)
     results = engine.serve(_requests(cfg, [4, 7, 3], [3, 2, 4]))
     for r in results:
         assert r.finish_reason == "length"
         assert r.ttft_s > 0.0
+        assert 0.0 <= r.queue_s < r.ttft_s
         # the first token is produced AT admission, before any decode tick
-        assert r.ttft_s <= r.prefill_s + r.decode_s + 1.0
+        assert r.ttft_s - r.queue_s <= r.prefill_s + r.decode_s + 1.0
+    # the third request waited in the queue for a free slot
+    assert results[2].queue_s > results[0].queue_s
     h = engine.metrics.histogram("serving/ttft_s")
     assert h.count == len(results)
     assert engine.metrics.histogram("serving/tbt_s").count > 0
@@ -259,6 +267,7 @@ def test_deadline_expiry_queued_and_resident(tiny):
     assert results[1].tokens.shape[-1] == 0         # dropped from the queue
     assert results[2].finish_reason == "length"
     assert results[2].tokens.shape[-1] == 3
+    assert not engine._submitted     # expiries close their TTFT stamps
 
 
 def test_zero_budget_request_gets_zero_tokens(tiny):
@@ -272,6 +281,7 @@ def test_zero_budget_request_gets_zero_tokens(tiny):
     assert results[0].tokens.shape == (0,)
     assert results[0].finish_reason == "length"
     assert results[1].tokens.shape == (2,)
+    assert not engine._submitted
 
 
 def test_deadline_checked_on_mid_admission_refill(tiny):
@@ -457,6 +467,116 @@ def test_chunked_rejects_invalid_config(tiny):
     with pytest.raises(ValueError, match="prefill_lanes"):
         SlotEngine(model, params, config=EngineConfig(
             n_slots=2, max_seq=64, prefill_chunk_len=4, prefill_lanes=0))
+
+
+# ---------------------------------------------------------------------------
+# Engine spans: the tick and the chunk split into their phases, and one
+# serve/request interval per request
+# ---------------------------------------------------------------------------
+def _kids(records, parent):
+    return sorted((r for r in records
+                   if r["type"] == "span" and r["parent"] == parent["span"]),
+                  key=lambda r: r["ts"])
+
+
+def _end(rec):
+    return rec["ts"] + rec["dur_s"]
+
+
+def _assert_phases(records, parent, names):
+    """``parent``'s child spans are ``names`` in order, inside it, and do
+    not overlap."""
+    kids = _kids(records, parent)
+    assert [k["name"] for k in kids] == names
+    assert parent["ts"] <= kids[0]["ts"] and _end(kids[-1]) <= _end(parent)
+    for a, b in zip(kids, kids[1:]):
+        assert _end(a) <= b["ts"]
+
+
+@pytest.mark.parametrize("chunk", [None, 4], ids=["whole", "chunked"])
+def test_engine_spans_split_tick_and_chunk(tiny, chunk):
+    """Traced serving, whole-prompt and chunked admission: every tick has
+    prepare/dispatch/sync phases and every chunk dispatch/sync; each
+    request has one serve/request whose queue and lane parts add up to
+    its TTFT; no engine span is open while the client runs; the chunk
+    events keep their schema; tokens and pools are as untraced."""
+    from repro.obs import ListSink, Tracer, get_tracer, set_tracer
+
+    cfg, model, params = tiny
+    lens, news = [5, 11, 3, 9], [4, 3, 5, 2]
+
+    def serve(on_token=None):
+        engine = SlotEngine(model, params, config=EngineConfig(
+            n_slots=2, max_seq=64, queue_capacity=8,
+            prefill_chunk_len=chunk))
+        return engine, engine.serve(_requests(cfg, lens, news, seed=5),
+                                    on_token=on_token)
+
+    _, want = serve()
+    sink = ListSink()
+    old = set_tracer(Tracer(sink))
+    open_at_yield = []
+    try:
+        engine, got = serve(lambda ev: open_at_yield.append(
+            list(get_tracer()._stack)))
+    finally:
+        set_tracer(old)
+    for w, g in zip(want, got):
+        np.testing.assert_array_equal(w.tokens, g.tokens)
+    assert engine.pool.stats.buffers_built == 1       # zero-alloc holds
+    sp = engine._scratch_pool.stats
+    assert sp.buffers_built == sp.capacity
+    assert open_at_yield and not any(open_at_yield)
+    assert not engine._submitted                      # every stamp closed
+
+    recs = sink.records
+    named = lambda n: [r for r in recs if r["name"] == n]
+    ticks = named("serve/tick")
+    assert ticks
+    for t in ticks:
+        _assert_phases(recs, t, ["serve/tick/prepare", "serve/tick/dispatch",
+                                 "serve/tick/sync"])
+        assert 1 <= t["attrs"]["active"] <= t["attrs"]["occupied"]
+
+    chunks, events = named("serve/chunk"), named("serve/prefill_chunk")
+    starts = named("serve/prefill_start")
+    if chunk is None:
+        assert not chunks and not events and not starts
+    else:
+        n_chunks = sum(len(chunk_schedule(n, chunk)) for n in lens)
+        assert len(chunks) == len(events) == n_chunks
+        assert len(starts) == len(lens)
+        for c, e in zip(chunks, events):
+            _assert_phases(recs, c, ["serve/chunk/dispatch",
+                                     "serve/chunk/sync"])
+            assert set(c["attrs"]) == {"uid", "chunk", "seg_len"}
+            assert e["type"] == "event" and e["parent"] is None
+            assert set(e["attrs"]) == {"uid", "chunk", "seg_len", "filled",
+                                       "chunk_s"}
+            assert [c["attrs"][k] for k in ("uid", "chunk", "seg_len")] == \
+                [e["attrs"][k] for k in ("uid", "chunk", "seg_len")]
+            # the event is stamped once the chunk's token is ready
+            assert _end(c) <= e["ts"]
+        for e in starts:
+            assert set(e["attrs"]) == {"uid", "prompt_len", "n_chunks"}
+
+    reqs = named("serve/request")
+    assert sorted(r["attrs"]["uid"] for r in reqs) == list(range(len(lens)))
+    results = {r.uid: r for r in got}
+    for r in reqs:
+        a, res = r["attrs"], results[r["attrs"]["uid"]]
+        assert r["type"] == "span" and r["parent"] is None
+        assert a["queue_s"] >= 0 and a["lane_s"] > 0
+        assert a["queue_s"] + a["lane_s"] == pytest.approx(r["dur_s"],
+                                                           abs=1e-9)
+        assert r["dur_s"] == pytest.approx(res.ttft_s, abs=1e-9)
+        assert a["queue_s"] == pytest.approx(res.queue_s, abs=1e-9)
+        assert a["prompt_len"] == lens[a["uid"]]
+        assert a["chunks"] == (1 if chunk is None else
+                               len(chunk_schedule(lens[a["uid"]], chunk)))
+    admits = {r["attrs"]["uid"]: r["attrs"]["ttft_s"]
+              for r in named("serve/admit")}
+    assert admits == pytest.approx({u: r.ttft_s for u, r in results.items()})
 
 
 @pytest.mark.slow

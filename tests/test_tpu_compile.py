@@ -106,6 +106,16 @@ def test_fused_seq_paper_width(compile_v5e, plan, batch, grad):
     assert text.count("tpu_custom_call") == (2 if grad else 1)
 
 
+def test_fused_seq_kernel_keeps_its_trace_name(compile_v5e):
+    """The kernel's custom call is named after the jitted
+    ``_lstm_seq_call`` that makes it; the device-trace readers of the
+    benchmark find the kernel by that name."""
+    text = compile_v5e(_plan_fn("fused_seq", PAPER, False),
+                       *_lstm_args(PAPER, 1)[:2])
+    [line] = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert line.strip().startswith("%_lstm_seq_call")
+
+
 @pytest.mark.parametrize("plan,batch", [("fused_seq_q8", 64),
                                         ("fused_seq", 256)])
 def test_fused_seq_fig56_grad(compile_v5e, plan, batch):
