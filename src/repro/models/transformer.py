@@ -397,7 +397,15 @@ def decode_step(params, cfg: ModelConfig, cache, batch: dict
 
     ``cache['pos']`` may be a scalar (lockstep waves) or a (B,) vector
     (slot-resident continuous batching, serving/slots.py) — attention
-    handles both; rwkv/mamba state is positionless either way."""
+    handles both; rwkv/mamba state is positionless either way.
+
+    An optional ``batch['active']`` (B,) bool mask keeps the cache-slot
+    leaves of inactive lanes as they came in.  The select runs per layer
+    inside the layer scan, on the (B, ...) slices the layer already reads
+    and writes, so XLA fuses it into the state update instead of making a
+    separate pass over the whole stacked pool.  ``pos`` is returned
+    advanced for every lane; the caller selects it
+    (steps.masked_decode_step)."""
     toks = batch["tokens"]
     if cfg.n_codebooks:
         x = jnp.zeros((toks.shape[0], 1, cfg.d_model), jnp.dtype(cfg.dtype))
@@ -408,20 +416,38 @@ def decode_step(params, cfg: ModelConfig, cache, batch: dict
         x = jnp.take(params["embed"], toks[:, None], axis=0)
     pos = cache["pos"]
     period = cfg.period
+    active = batch.get("active")
 
-    def group_fn(x, xs):
-        group_params, cache_slots = xs
+    def keep_inactive(new, old):
+        # per-layer slot leaves are (B, ...): batch axis is 0
+        return jnp.where(active.reshape((-1,) + (1,) * (new.ndim - 1)),
+                         new, old)
+
+    def group_fn(carry, xs):
+        # the stacked slots ride in the carry and each layer's new state is
+        # written back in place: as the scan's ys they would be a second
+        # pool that the donated cache then has to be copied from
+        x, slots = carry
+        group_params, g = xs
         new_slots = []
         aux = {}
         for s in range(period):
-            x, new_c, aux = _apply_block(group_params[s], cfg, s, x,
-                                         cache_slots[s], None, pos, aux,
-                                         "decode")
-            new_slots.append(new_c)
-        return x, tuple(new_slots)
+            old = jax.tree.map(
+                lambda a: jax.lax.dynamic_index_in_dim(a, g, keepdims=False),
+                slots[s])
+            x, new_c, aux = _apply_block(group_params[s], cfg, s, x, old,
+                                         None, pos, aux, "decode")
+            if active is not None:
+                new_c = jax.tree.map(keep_inactive, new_c, old)
+            new_slots.append(jax.tree.map(
+                lambda a, n: jax.lax.dynamic_update_index_in_dim(a, n, g, 0),
+                slots[s], new_c))
+        return (x, tuple(new_slots)), None
 
-    x, new_slots = jax.lax.scan(group_fn, x,
-                                (params["blocks"], cache["slots"]))
+    n_groups = jax.tree.leaves(params["blocks"])[0].shape[0]
+    (x, new_slots), _ = jax.lax.scan(
+        group_fn, (x, cache["slots"]),
+        (params["blocks"], jnp.arange(n_groups)))
     x = common.apply_norm(params["final_norm"], x, cfg.norm)
     logits = lm_logits(params, cfg, x)[:, 0] if not cfg.n_codebooks else \
         lm_logits(params, cfg, x)[:, :, 0]

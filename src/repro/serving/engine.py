@@ -454,10 +454,11 @@ class SlotEngine(_EngineBase):
 
     def _decode_plans(self, extra: dict[str, Callable]
                       ) -> dict[str, Callable]:
-        # every plan is wrapped with the active-mask select (free/finished
-        # lanes keep their state untouched), the per-lane finite guard and
-        # greedy sampling, so one dispatch per tick yields
-        # (sampled tokens, lane_ok, cache) directly
+        # every plan is wrapped with the active mask (free/finished lanes
+        # keep their state untouched; the plan receives batch['active'] and
+        # must honour it), the per-lane finite guard and greedy sampling,
+        # so one dispatch per tick yields (sampled tokens, lane_ok, cache)
+        # directly
         def masked(fn=None):
             def plan(p, c, b):
                 step = None if fn is None else (

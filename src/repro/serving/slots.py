@@ -17,7 +17,10 @@ independent **slots**:
   (``cache.at[:, i]``-style ``dynamic_update_slice``, no reallocation);
 * every tick runs ONE fused masked decode step across all lanes
   (steps.masked_decode_step) — free/finished lanes are carried by a per-slot
-  active mask and per-lane ``pos`` counters inside the batch dict;
+  active mask and per-lane ``pos`` counters inside the batch dict; the
+  step selects each layer's state against the mask inside its layer scan
+  (models/transformer.decode_step), so a decode plan must honour
+  ``batch['active']``;
 * retirement zeroes JUST that lane in place (core/state.lane_zero under a
   donated jit) and the next queued request is admitted immediately.
 

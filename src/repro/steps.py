@@ -90,24 +90,20 @@ def masked_decode_step(cfg: ModelConfig, params: Any, cache: Any,
     per-lane (B,) vector form.  Inactive lanes (free slots, finished
     requests) still ride through the fixed-shape computation — that is the
     point: ONE dispatch per tick regardless of occupancy — but their cache
-    slices and position counters are reselected from the input cache, so a
-    dead lane is semantically a no-op and its logits are garbage the caller
-    must ignore.  ``step_fn`` defaults to ``decode_step``; alternate decode
-    plans are wrapped the same way by the serving engine.
+    slices and position counters keep their input values, so a dead lane is
+    semantically a no-op and its logits are garbage the caller must ignore.
+
+    The cache-slot select happens inside the step's layer scan
+    (transformer.decode_step, per layer), where the state is already read
+    and written; only the (B,) ``pos`` is selected here.  So ``step_fn``
+    (default ``decode_step``; the serving engine wraps alternate decode
+    plans the same way) receives ``batch['active']`` and must honour it.
     """
     step = step_fn or decode_step
     active = batch["active"]
-    logits, new_cache = step(cfg, params, cache,
-                             {k: v for k, v in batch.items() if k != "active"})
-
-    def sel(new, old):
-        # cache slot leaves are (n_groups, B, ...): batch axis is 1
-        m = active.reshape((1, -1) + (1,) * (new.ndim - 2))
-        return jnp.where(m, new, old)
-
-    slots = jax.tree.map(sel, new_cache["slots"], cache["slots"])
+    logits, new_cache = step(cfg, params, cache, batch)
     pos = jnp.where(active, new_cache["pos"], cache["pos"])
-    return logits, {"pos": pos, "slots": slots}
+    return logits, {"pos": pos, "slots": new_cache["slots"]}
 
 
 def guarded_decode_step(cfg: ModelConfig, params: Any, cache: Any,
