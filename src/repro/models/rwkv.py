@@ -4,18 +4,37 @@ and channel-mix, with both execution plans:
 * chunked scan (default) — MobiRNN-style coarse work units over the sequence
   (matmul form within a chunk, state carried across chunks); mirrors the
   Pallas kernel kernels/wkv6.py and is validated against the per-step oracle.
-* per-step scan — the fine-grained reference plan (decode uses its step fn).
+* per-step scan — the fine-grained reference plan (``wkv_step``; decode
+  runs the same step as the kernel kernels/wkv_decode.py).
 
 Token-shift state and the (dk x dv) wkv state per head are the recurrent
-state buffers managed by the preallocated decode cache (core/state.py).
+state buffers of the preallocated decode cache (models/transformer.init_cache).
+
+Packed wkv state.  The cache stores each layer's f32 wkv state with ``p``
+heads side by side on the minor axis, ``(B, H/p, dk, p*dv)`` instead of
+``(B, H, dk, dv)``: lane ``l`` of packed row ``j`` holds head
+``p*j + l // dv``, column ``l % dv`` (pack_state / unpack_state).  A TPU
+tiles an f32 array's minor axis in 128 lanes, so a 64-wide head row would
+be half padding; ``p = 128 // dv`` fills the tile.  ``p`` comes from the
+shapes alone (heads_per_row): ``128 // dv`` when ``dv`` divides 128 and
+``p`` divides H, else 1, the plain layout.  Decode works on the packed
+rows directly: kernels/wkv_decode.py steps a layer of the stacked pool in
+place, and ``wkv_step`` is the same step in jnp.  The full-sequence scans
+take ``(B, H, dk, dv)``; the caller converts at their entry and exit
+(transformer._apply_block).
 """
 from __future__ import annotations
+
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.core.tiling import LANES
+from repro.kernels.wkv_decode import wkv_decode
 from repro.models import common
+from repro.obs import trace as trace_lib
 from repro.partitioning import Annot, shard_map
 
 N_MIX = 5  # w, k, v, r, g interpolation vectors
@@ -65,7 +84,12 @@ def _ddlerp(p: dict, x: jax.Array, sx: jax.Array) -> tuple[jax.Array, ...]:
     B, S, d = x.shape
     xxx = x + sx * p["maa_x"]
     lora = jnp.tanh(xxx @ p["tm_w1"]).reshape(B, S, N_MIX, 32)
-    mixes = jnp.einsum("bsnr,nrd->nbsd", lora, p["tm_w2"])   # (5,B,S,d)
+    # f32 weights, f32 product: at the TPU's default precision XLA rounds
+    # the whole (n_groups, 5, 32, d) stack to bf16 once, outside the
+    # decode layer scan, and then moves that 26 MB copy in and out of
+    # VMEM every layer (PERF.md §6)
+    mixes = jnp.einsum("bsnr,nrd->nbsd", lora, p["tm_w2"],
+                       precision="highest")                  # (5,B,S,d)
     outs = []
     for i in range(N_MIX):
         outs.append(x + sx * (p["maa"][i] + mixes[i]))
@@ -133,15 +157,80 @@ def wkv_chunked(r, k, v, logw, u, state, chunk: int):
     return out, state
 
 
+def heads_per_row(cfg: ModelConfig) -> int:
+    """Heads the cache packs side by side on one 128-lane state row."""
+    H, dv = n_heads(cfg), cfg.ssm.head_dim
+    if dv < LANES and LANES % dv == 0 and H % (LANES // dv) == 0:
+        return LANES // dv
+    return 1
+
+
+def pack_state(s: jax.Array, p: int) -> jax.Array:
+    """(..., H, dk, dv) -> (..., H/p, dk, p*dv), heads ``p*j .. p*j+p-1``
+    side by side on packed row ``j``."""
+    if p == 1:
+        return s
+    *lead, H, dk, dv = s.shape
+    s = jnp.swapaxes(s.reshape(*lead, H // p, p, dk, dv), -3, -2)
+    return s.reshape(*lead, H // p, dk, p * dv)
+
+
+def unpack_state(s: jax.Array, p: int) -> jax.Array:
+    """Inverse of pack_state: (..., H/p, dk, p*dv) -> (..., H, dk, dv)."""
+    if p == 1:
+        return s
+    *lead, Hp, dk, pdv = s.shape
+    s = jnp.swapaxes(s.reshape(*lead, Hp, dk, p, pdv // p), -3, -2)
+    return s.reshape(*lead, Hp * p, dk, pdv // p)
+
+
+class PoolLayer(NamedTuple):
+    """Layer ``index`` of the stacked packed wkv pool (n_groups, B, ...),
+    which decode steps in place; lanes with ``active`` False keep their
+    state."""
+    pool: jax.Array
+    index: jax.Array
+    active: jax.Array
+
+
+def record_layout(cfg: ModelConfig, pool_leaf) -> None:
+    """``plan/state_layout`` trace event naming the wkv state layout a
+    decode plan runs on.  Fires at trace time: once per compilation."""
+    tracer = trace_lib.get_tracer()
+    if tracer.enabled:
+        tracer.event("plan/state_layout", family="rwkv6",
+                     heads_per_row=heads_per_row(cfg),
+                     shape=list(pool_leaf.shape),
+                     bytes=pool_leaf.size * pool_leaf.dtype.itemsize)
+
+
+def _over_rows(a: jax.Array, p: int, dv: int) -> jax.Array:
+    """Per-head vectors (..., H, dk) laid over the packed rows:
+    (..., H/p, dk, p*dv), lane ``l`` of row ``j`` holding head
+    ``p*j + l // dv``'s vector, by a select on a lane iota."""
+    *lead, H, dk = a.shape
+    a = a.reshape(*lead, H // p, p, dk, 1)
+    head = jax.lax.broadcasted_iota(jnp.int32, (p * dv,), 0) // dv
+    out = a[..., p - 1, :, :]
+    for q in range(p - 1):
+        out = jnp.where(head == q, a[..., q, :, :], out)
+    return out
+
+
 def wkv_step(r, k, v, logw, u, state):
-    """Single decode step.  r,k,logw: (B,H,dk); v: (B,H,dv);
-    state: (B,H,dk,dv)."""
+    """Single decode step.  r,k,logw: (B,H,dk); v: (B,H,dv); u: (H,dk);
+    state: (B,H/p,dk,p*dv), packed as pack_state lays it out, with ``p``
+    read from its minor axis (an unpacked (B,H,dk,dv) state is p = 1).
+    Returns out (B,H,dv) and the new state in the same layout."""
     f32 = jnp.float32
-    r, k, v, logw = (a.astype(f32) for a in (r, k, v, logw))
-    kv = jnp.einsum("bhk,bhv->bhkv", k, v)
-    out = jnp.einsum("bhk,bhkv->bhv", r, state + u.astype(f32)[..., None] * kv)
-    state = jnp.exp(logw)[..., None] * state + kv
-    return out, state
+    B, H, dv = v.shape
+    p = state.shape[-1] // dv
+    r4, k4, w4 = (_over_rows(a, p, dv) for a in (
+        r.astype(f32), k.astype(f32), jnp.exp(logw.astype(f32))))
+    u4 = _over_rows(u.astype(f32), p, dv)
+    kv = k4 * v.astype(f32).reshape(B, H // p, 1, p * dv)
+    out = jnp.sum(r4 * (state + u4 * kv), axis=-2)     # (B,H/p,p*dv)
+    return out.reshape(B, H, dv), w4 * state + kv
 
 
 def apply_tmix(p: dict, cfg: ModelConfig, x: jax.Array, x_prev: jax.Array,
@@ -291,16 +380,18 @@ def _apply_tmix_seqpar(p: dict, cfg: ModelConfig, x: jax.Array,
 
 
 def step_tmix(p: dict, cfg: ModelConfig, x: jax.Array, x_prev: jax.Array,
-              state: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """One-token time-mix.  x: (B,1,d)."""
+              layer: PoolLayer) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """One-token time-mix.  x: (B,1,d).  Steps ``layer`` of the packed
+    pool in place (kernels/wkv_decode.py) and returns (out, shift', pool')."""
     B, _, d = x.shape
-    H, dh = n_heads(cfg), cfg.ssm.head_dim
+    H = n_heads(cfg)
+    record_layout(cfg, layer.pool)
     r, k, v, g, logw, shift = _project(p, cfg, x, x_prev)
-    out, state = wkv_step(r[:, 0], k[:, 0], v[:, 0], logw[:, 0], p["u"],
-                          state)
+    out, pool = wkv_decode(layer.pool, layer.index, layer.active, r[:, 0],
+                           k[:, 0], logw[:, 0], v[:, 0], p["u"])
     out = common.apply_groupnorm(p["gn"], out.reshape(B, 1, d), H)
     out = (out.astype(x.dtype) * g) @ p["wo"]
-    return out, shift, state
+    return out, shift, pool
 
 
 # ---------------------------------------------------------------------------

@@ -121,7 +121,13 @@ def abstract_params(cfg: ModelConfig, key=None) -> tuple[Any, Any]:
 # Cache
 # ---------------------------------------------------------------------------
 def init_cache(cfg: ModelConfig, batch: int, max_seq: int) -> dict:
-    """Annotated zero decode cache (the preallocated state pool contents)."""
+    """Annotated zero decode cache (the preallocated state pool contents).
+
+    An rwkv6 slot's ``wkv`` leaf is f32 (n_groups, batch, H/p, dk, p*dv):
+    ``p = rwkv.heads_per_row(cfg)`` heads side by side on each 128-lane
+    row (``128 // dv`` when dv divides 128 and p divides H, else 1; see
+    the models/rwkv.py docstring), so a TPU stores no lane padding.
+    rwkv6-3b's 40 heads of 64 give p = 2: f32[32, B, 20, 64, 128]."""
     dtype = jnp.dtype(cfg.dtype)
     period = cfg.period
     n_groups = cfg.n_layers // period
@@ -132,13 +138,12 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int) -> dict:
             slot = attention.init_cache_slot(cfg, n_groups, batch, max_seq,
                                              dtype)
         elif cfg.ssm.kind == "rwkv6":
-            H, dh = rwkv.n_heads(cfg), cfg.ssm.head_dim
             d = cfg.d_model
             slot = {
                 "shift_t": Annot(jnp.zeros((n_groups, batch, d), dtype),
                                  ("layers", "batch", "embed_nofsdp")),
-                "wkv": Annot(jnp.zeros((n_groups, batch, H, dh, dh),
-                                       jnp.float32),
+                "wkv": Annot(jnp.zeros((n_groups, batch)
+                                       + _wkv_shape(cfg), jnp.float32),
                              ("layers", "batch", "heads", None, None)),
                 "shift_c": Annot(jnp.zeros((n_groups, batch, d), dtype),
                                  ("layers", "batch", "embed_nofsdp")),
@@ -155,6 +160,12 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int) -> dict:
         slots.append(slot)
     return {"pos": Annot(jnp.zeros((), jnp.int32), ()),
             "slots": tuple(slots)}
+
+
+def _wkv_shape(cfg: ModelConfig) -> tuple[int, int, int]:
+    """One lane's packed rwkv6 wkv state: (H/p, dk, p*dv)."""
+    p, dh = rwkv.heads_per_row(cfg), cfg.ssm.head_dim
+    return (rwkv.n_heads(cfg) // p, dh, p * dh)
 
 
 def abstract_cache(cfg: ModelConfig, batch: int, max_seq: int):
@@ -192,9 +203,8 @@ def _dummy_cache_slot(cfg: ModelConfig, slot: int, batch: int) -> dict:
     if kind == "attn":
         return {}
     if cfg.ssm.kind == "rwkv6":
-        H, dh = rwkv.n_heads(cfg), cfg.ssm.head_dim
         return {"shift_t": jnp.zeros((batch, cfg.d_model), dtype),
-                "wkv": jnp.zeros((batch, H, dh, dh), jnp.float32),
+                "wkv": jnp.zeros((batch,) + _wkv_shape(cfg), jnp.float32),
                 "shift_c": jnp.zeros((batch, cfg.d_model), dtype)}
     di, ds, dc = mamba.d_inner(cfg), cfg.ssm.d_state, cfg.ssm.d_conv
     return {"conv": jnp.zeros((batch, dc - 1, di), dtype),
@@ -224,9 +234,19 @@ def _apply_block(slot_p, cfg: ModelConfig, slot: int, x, cache_slot,
                 new_cache.update(attention.prefill_cache(
                     slot_p["mix"], h, cache_slot, cfg, positions))
     elif cfg.ssm.kind == "rwkv6":
-        fn = rwkv.step_tmix if mode == "decode" else rwkv.apply_tmix
-        out, shift, state = fn(slot_p["mix"], cfg, h,
-                               cache_slot["shift_t"], cache_slot["wkv"])
+        # decode steps the packed wkv pool in place (rwkv.PoolLayer); the
+        # full-sequence scans take (B, H, dk, dv), so convert at their
+        # entry and exit
+        if mode == "decode":
+            out, shift, state = rwkv.step_tmix(
+                slot_p["mix"], cfg, h, cache_slot["shift_t"],
+                cache_slot["wkv"])
+        else:
+            p = rwkv.heads_per_row(cfg)
+            out, shift, state = rwkv.apply_tmix(
+                slot_p["mix"], cfg, h, cache_slot["shift_t"],
+                rwkv.unpack_state(cache_slot["wkv"], p))
+            state = rwkv.pack_state(state, p)
         new_cache.update(shift_t=shift, wkv=state)
     else:
         fn = mamba.step_mamba if mode == "decode" else mamba.apply_mamba
@@ -390,6 +410,14 @@ def prefill_chunk(params, cfg: ModelConfig, cache, batch: dict
     return logits, new_cache
 
 
+def _pooled_leaf(cfg: ModelConfig, slot: int) -> str | None:
+    """The cache leaf that a decode block steps in place in the stacked
+    pool instead of taking its layer's slice: rwkv6's packed wkv state."""
+    if cfg.layer_kind(slot) != "attn" and cfg.ssm.kind == "rwkv6":
+        return "wkv"
+    return None
+
+
 def decode_step(params, cfg: ModelConfig, cache, batch: dict
                 ) -> tuple[jax.Array, dict]:
     """One decode step.  batch['tokens']: (B,) or (B,K) audio.
@@ -403,8 +431,10 @@ def decode_step(params, cfg: ModelConfig, cache, batch: dict
     leaves of inactive lanes as they came in.  The select runs per layer
     inside the layer scan, on the (B, ...) slices the layer already reads
     and writes, so XLA fuses it into the state update instead of making a
-    separate pass over the whole stacked pool.  ``pos`` is returned
-    advanced for every lane; the caller selects it
+    separate pass over the whole stacked pool.  rwkv6's packed wkv pool
+    is not sliced at all: kernels/wkv_decode.py steps its layer in place
+    in the stacked pool and keeps inactive lanes itself.  ``pos`` is
+    returned advanced for every lane; the caller selects it
     (steps.masked_decode_step)."""
     toks = batch["tokens"]
     if cfg.n_codebooks:
@@ -417,6 +447,7 @@ def decode_step(params, cfg: ModelConfig, cache, batch: dict
     pos = cache["pos"]
     period = cfg.period
     active = batch.get("active")
+    lanes = jnp.ones(toks.shape[:1], bool) if active is None else active
 
     def keep_inactive(new, old):
         # per-layer slot leaves are (B, ...): batch axis is 0
@@ -432,16 +463,26 @@ def decode_step(params, cfg: ModelConfig, cache, batch: dict
         new_slots = []
         aux = {}
         for s in range(period):
+            stacked = dict(slots[s])
+            leaf = _pooled_leaf(cfg, s)
+            pool = stacked.pop(leaf) if leaf else None
             old = jax.tree.map(
                 lambda a: jax.lax.dynamic_index_in_dim(a, g, keepdims=False),
-                slots[s])
-            x, new_c, aux = _apply_block(group_params[s], cfg, s, x, old,
-                                         None, pos, aux, "decode")
+                stacked)
+            block_in = old if leaf is None else dict(
+                old, **{leaf: rwkv.PoolLayer(pool, g, lanes)})
+            x, new_c, aux = _apply_block(group_params[s], cfg, s, x,
+                                         block_in, None, pos, aux, "decode")
+            if leaf:
+                pool = new_c.pop(leaf)
             if active is not None:
                 new_c = jax.tree.map(keep_inactive, new_c, old)
-            new_slots.append(jax.tree.map(
+            new_c = jax.tree.map(
                 lambda a, n: jax.lax.dynamic_update_index_in_dim(a, n, g, 0),
-                slots[s], new_c))
+                stacked, new_c)
+            if leaf:
+                new_c[leaf] = pool
+            new_slots.append(new_c)
         return (x, tuple(new_slots)), None
 
     n_groups = jax.tree.leaves(params["blocks"])[0].shape[0]

@@ -46,7 +46,8 @@ def _params(model):
 def _filled_cache(model, n, max_seq, seed):
     """A pool cache whose every float leaf holds seeded noise, so a lane
     that kept its state and a lane that was advanced cannot agree by
-    accident."""
+    accident.  Both formulations step this one cache, rwkv6's packed wkv
+    leaf included, as ``model.init_cache`` lays it out."""
     cache, _ = split(model.init_cache(n, max_seq))
     leaves, tree = jax.tree.flatten(cache["slots"])
     keys = jax.random.split(jax.random.PRNGKey(seed), len(leaves))

@@ -586,7 +586,10 @@ def test_engine_spans_split_tick_and_chunk(tiny, chunk):
 def test_chunked_prefill_token_identity_per_family(arch):
     """Chunked admission is token-identical to whole-prompt admission for
     every serving family — attention replays the exact positions through
-    the chunk mask, rwkv/mamba prefill FROM their cache state natively."""
+    the chunk mask, rwkv/mamba prefill FROM their cache state natively —
+    and both are the greedy tokens of the cacheless full-sequence
+    forward, so a state layout the prefill and the decode tick share
+    (rwkv6's packed wkv pool) cannot agree with itself by mistake."""
     cfg = get_arch(arch).reduced()
     model = registry.build(cfg)
     params, _ = split(model.init(jax.random.PRNGKey(0)))
@@ -596,9 +599,28 @@ def test_chunked_prefill_token_identity_per_family(arch):
     chunked = SlotEngine(model, params, config=EngineConfig(
         n_slots=2, max_seq=32, prefill_chunk_len=4,
         prefill_lanes=2)).serve(reqs)
-    for w, g in zip(whole, chunked):
+    for w, g, want in zip(whole, chunked, _forward_greedy(model, params,
+                                                          reqs, 32)):
         assert np.array_equal(w.tokens, g.tokens), (w.uid, w.tokens,
                                                     g.tokens)
+        assert np.array_equal(g.tokens, want), (g.uid, g.tokens, want)
+
+
+def _forward_greedy(model, params, reqs, width):
+    """Greedy continuations from ``model.forward`` over the whole
+    sequence so far, right-padded to one ``width`` (causal: the padding
+    never reaches the position read)."""
+    fwd = jax.jit(lambda p, t: model.forward(p, {"tokens": t},
+                                             inference=True)[0])
+    out = []
+    for r in reqs:
+        toks = list(r.prompt)
+        for _ in range(r.max_new_tokens):
+            row = np.zeros((1, width), np.int32)
+            row[0, :len(toks)] = toks
+            toks.append(int(np.argmax(fwd(params, row)[0, len(toks) - 1])))
+        out.append(np.asarray(toks[len(r.prompt):], np.int32))
+    return out
 
 
 # ---------------------------------------------------------------------------

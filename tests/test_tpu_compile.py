@@ -19,7 +19,9 @@ every test worker imports every test file.
 """
 from __future__ import annotations
 
+import dataclasses
 import os
+import re
 import traceback
 
 import jax
@@ -63,8 +65,9 @@ def compile_v5e(one_chip):
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
                                            sharding=one_chip), tree)
 
-    def compile_(fn, *args):
-        return jax.jit(fn).lower(*place(args)).compile().as_text()
+    def compile_(fn, *args, donate_argnums=()):
+        return jax.jit(fn, donate_argnums=donate_argnums).lower(
+            *place(args)).compile().as_text()
 
     enabled = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -152,6 +155,80 @@ def test_streamed_paper_width(compile_v5e, quantized, grad):
               jax.ShapeDtypeStruct((B, T, H), jnp.float32))
     text = compile_v5e(fn, *shapes)
     assert text.count("tpu_custom_call") == (2 if grad else 1)
+
+
+def test_rwkv6_decode_plan_keeps_the_packed_pool(compile_v5e, monkeypatch):
+    """rwkv6-3b's decode plan at full width (2 layers, 8 lanes), donated
+    as the engine runs it: the wkv pool rides the layer scan's carry as
+    f32[2, 8, 20, 64, 128], two heads to a 128-lane row, and the decode
+    kernel steps it in place.  No f32 buffer of the unpacked
+    (..., 40, 64, 64) layout is left, and nothing copies or transposes
+    the pool."""
+    from repro import steps
+    from repro.configs import get_arch
+    from repro.kernels import wkv_decode
+    from repro.models import registry
+
+    # the kernel asks the default backend, which here is the CPU
+    monkeypatch.setattr(wkv_decode, "resolve_interpret", lambda i: False)
+    cfg = dataclasses.replace(get_arch("rwkv6-3b"), n_layers=2)
+    model = registry.build(cfg)
+    B = 8
+
+    def plan(p, c, b):
+        logits, ok, c = steps.guarded_decode_step(cfg, p, c, b)
+        return steps.greedy_sample(logits), ok, c
+
+    text = compile_v5e(plan, *_rwkv6_decode_args(model, B),
+                       donate_argnums=(1,))
+    pool = "f32[2,8,20,64,128]"
+    assert any(" while(" in ln and pool in ln for ln in text.splitlines())
+    assert "tpu_custom_call" in text
+    assert not re.search(r"f32\[(\d+,)*40,64,64\]", text)
+    for ln in text.splitlines():
+        if pool in ln:
+            name = ln.split(" = ")[0]
+            assert not re.search(r"copy|transpose", name), ln[:200]
+            assert not re.search(r"\b(copy|copy-start|transpose)\(", ln), \
+                ln[:200]
+
+
+def test_rwkv6_decode_plan_keeps_weights_out_of_a_vmem_churn(compile_v5e,
+                                                            monkeypatch):
+    """rwkv6-3b's whole decode plan (32 layers, 64 lanes) for a described
+    v5e: no weight stack is held in VMEM across the layer scan and
+    evicted and refetched at every layer (an async copy out, sliced
+    copies back in and a ``ConcatBitcast``).  With the packed state that
+    happened to the bf16 rounding of the ddlerp LoRA stack ``tm_w2`` and
+    cost 1.9 ms a tick on the chip, until its einsum ran in f32."""
+    from repro import steps
+    from repro.configs import get_arch
+    from repro.kernels import wkv_decode
+    from repro.models import registry
+
+    monkeypatch.setattr(wkv_decode, "resolve_interpret", lambda i: False)
+    cfg = get_arch("rwkv6-3b")
+    model = registry.build(cfg)
+
+    def plan(p, c, b):
+        logits, ok, c = steps.guarded_decode_step(cfg, p, c, b)
+        return steps.greedy_sample(logits), ok, c
+
+    text = compile_v5e(plan, *_rwkv6_decode_args(model, 64),
+                       donate_argnums=(1,))
+    assert "ConcatBitcast" not in text
+    assert "bf16[32,5,32,2560]" not in text
+
+
+def _rwkv6_decode_args(model, lanes):
+    """Shapes of the engine's decode plan arguments: params, the pool
+    with per-lane positions, and the tick's batch."""
+    cache = dict(model.abstract_cache(lanes, 16)[0],
+                 pos=_s((lanes,), jnp.int32))
+    batch = {"tokens": _s((lanes,), jnp.int32),
+             "active": _s((lanes,), jnp.bool_),
+             "poison": _s((lanes,), jnp.bool_)}
+    return model.abstract_params()[0], cache, batch
 
 
 # ---------------------------------------------------------------------------
