@@ -756,19 +756,16 @@ def bench_fig7_load() -> None:
 
 # ---------------------------------------------------------------------------
 def bench_serving() -> None:
-    """Wave vs slot engine on a RAGGED workload: mixed prompt lengths and an
-    8x ``max_new_tokens`` spread.  The wave engine pads every request in a
-    wave to the longest prompt and the longest token budget, so short
-    requests burn dead ticks; the slot engine retires each lane the step it
-    finishes and admits the next queued request — same model, same plans,
-    higher tokens/sec.  Also asserts the slot engine's zero-allocation
-    invariant (StatePool stats) after warmup."""
+    """The slot engine on a RAGGED workload: mixed prompt lengths and an
+    8x ``max_new_tokens`` spread; each lane retires the step it finishes
+    and admits the next queued request.  Also asserts the engine's
+    zero-allocation invariant (StatePool stats) after warmup."""
     import dataclasses
 
     from repro.configs import get_arch
     from repro.models import registry
     from repro.partitioning import split
-    from repro.serving import Engine, EngineConfig, Request, SlotEngine
+    from repro.serving import EngineConfig, Request, SlotEngine
 
     cfg = dataclasses.replace(
         get_arch("qwen2-0.5b").reduced(), n_layers=2, d_model=64,
@@ -787,15 +784,6 @@ def bench_serving() -> None:
                 for i, (p, n) in enumerate(zip(prompts, news))]
 
     n_tok = sum(news)
-    wave = Engine(model, params, config=EngineConfig(
-        n_slots=4, max_seq=64, pool_capacity=1))
-    wave.serve(reqs())                                   # compile/warmup
-    t0 = time.perf_counter()
-    wave.serve(reqs())
-    t_wave = time.perf_counter() - t0
-    row("serving/wave_ragged", t_wave * 1e6 / n_tok,
-        f"tok_per_s={n_tok / t_wave:.1f}")
-
     slot = SlotEngine(model, params, config=EngineConfig(
         n_slots=4, max_seq=64, queue_capacity=8))
     slot.serve(reqs())                                   # compile/warmup
@@ -813,11 +801,12 @@ def bench_serving() -> None:
     # donation; pool stats corroborate that none were rebuilt)
     assert live1 <= live0, (live0, live1)
     assert (slot.pool.stats.buffers_built,
-            slot._scratch_pool.stats.buffers_built) == (1, 1), \
+            slot._scratch_pool.stats.buffers_built) == (
+                1, slot.prefill_lanes), \
         "slot engine rebuilt pool buffers on the serving path"
     row("serving/slot_ragged", t_slot * 1e6 / n_tok,
-        f"tok_per_s={n_tok / t_slot:.1f},speedup_vs_wave="
-        f"{t_wave / t_slot:.2f}x,live_buffers_delta={live1 - live0}")
+        f"tok_per_s={n_tok / t_slot:.1f},"
+        f"live_buffers_delta={live1 - live0}")
 
     # per-request latency distributions from the engine's always-on obs
     # metrics (accumulated over warmup + timed serves): TTFT is
@@ -830,15 +819,10 @@ def bench_serving() -> None:
     row("serving/slot_tbt_p50", tbt["p50"] * 1e6,
         f"p99_us={tbt['p99'] * 1e6:.1f},n={tbt['count']}")
 
-    # TTFT under contention (ISSUE 10 headline): short requests queued
-    # behind long-prompt adversaries.  Whole-prompt admission stalls the
-    # tick loop for each adversary's full prefill; chunked admission
-    # interleaves, bounding any single stall at ~one chunk.  NOTE the
-    # wall-clock rows track a tradeoff, not a one-way win: on this tiny
-    # model a whole 48-token prefill is ONE sub-ms dispatch, so the
-    # per-chunk dispatch overhead chunking adds can exceed the stall it
-    # removes — the granularity bound itself is asserted structurally in
-    # --prefill-smoke, where it is model-size-independent.
+    # TTFT under contention: short requests queued behind long-prompt
+    # adversaries.  Chunked admission interleaves, bounding any single
+    # stall at ~one chunk; the granularity bound itself is asserted
+    # structurally in --prefill-smoke, where it is model-size-independent.
     adv_lens = [48, 4, 48, 4, 48, 4, 4, 4]               # adversary, short, ...
     adv_news = [4, 8, 4, 8, 4, 8, 8, 8]
     adv_prompts = [rng.integers(0, cfg.vocab, (l,)).astype(np.int32)
@@ -849,35 +833,34 @@ def bench_serving() -> None:
                 for i, (p, n) in enumerate(zip(adv_prompts, adv_news))]
 
     short_uids = {i for i, l in enumerate(adv_lens) if l == 4}
-    for label, config in (
-            ("whole", EngineConfig(n_slots=2, max_seq=64, queue_capacity=8)),
-            ("chunked", EngineConfig(n_slots=2, max_seq=64, queue_capacity=8,
-                                     prefill_chunk_len=8, prefill_lanes=2))):
-        eng = SlotEngine(model, params, config=config)
-        eng.serve(adv_reqs())                            # compile/warmup
-        first_tok: dict[int, float] = {}
+    eng = SlotEngine(model, params, config=EngineConfig(
+        n_slots=2, max_seq=64, queue_capacity=8, prefill_chunk_len=8,
+        prefill_lanes=2))
+    eng.serve(adv_reqs())                                # compile/warmup
+    first_tok: dict[int, float] = {}
 
-        def on_token(ev, first_tok=first_tok):
-            if ev.token is not None and ev.uid not in first_tok:
-                first_tok[ev.uid] = time.perf_counter()
+    def on_token(ev):
+        if ev.token is not None and ev.uid not in first_tok:
+            first_tok[ev.uid] = time.perf_counter()
 
-        t0 = time.perf_counter()
-        eng.serve(adv_reqs(), on_token=on_token)
-        # submit-to-first-token for the SHORT requests: includes the queue
-        # wait behind adversary prefills, the number chunking improves
-        short_ttfts = sorted(first_tok[u] - t0 for u in short_uids)
-        p50 = short_ttfts[len(short_ttfts) // 2]
-        row(f"serving/adversary_short_ttft_p50_{label}", p50 * 1e6,
-            f"p99_us={short_ttfts[-1] * 1e6:.1f},n={len(short_ttfts)},"
-            f"adversary_prompt=48")
+    t0 = time.perf_counter()
+    eng.serve(adv_reqs(), on_token=on_token)
+    # submit-to-first-token for the SHORT requests: includes the queue
+    # wait behind adversary prefills, the number chunking improves
+    short_ttfts = sorted(first_tok[u] - t0 for u in short_uids)
+    p50 = short_ttfts[len(short_ttfts) // 2]
+    row("serving/adversary_short_ttft_p50_chunked", p50 * 1e6,
+        f"p99_us={short_ttfts[-1] * 1e6:.1f},n={len(short_ttfts)},"
+        f"adversary_prompt=48")
 
 
 def bench_prefill_smoke() -> None:
     """CI smoke (fast job): the ISSUE 10 chunked-prefill acceptance,
     executed.
 
-    Asserts (a) chunked admission is greedy-token-identical to
-    whole-prompt admission on a tiny dense AND a tiny rwkv model; (b) the
+    Asserts (a) admission is greedy-token-identical at two chunk lengths
+    (the default, capped at ``max_seq``, and 4) on a tiny dense AND a tiny
+    rwkv model; (b) the
     compiled-shape contract — exactly ONE prefill-chunk executable per
     distinct segment length used (the schedule's shape set is {C} plus
     descending powers of two for the remainder); (c) the TTFT-adversary
@@ -917,14 +900,14 @@ def bench_prefill_smoke() -> None:
             return [Request(i, p, max_new_tokens=n)
                     for i, (p, n) in enumerate(zip(prompts, news))]
 
-        whole = SlotEngine(model, params, config=EngineConfig(
+        longer = SlotEngine(model, params, config=EngineConfig(
             n_slots=2, max_seq=32)).serve(reqs())
         eng = SlotEngine(model, params, config=EngineConfig(
             n_slots=2, max_seq=32, prefill_chunk_len=4, prefill_lanes=2))
         chunked = eng.serve(reqs())
-        for w, g in zip(whole, chunked):
+        for w, g in zip(longer, chunked):
             assert np.array_equal(w.tokens, g.tokens), \
-                f"{arch} uid {w.uid}: chunked != whole-prompt tokens"
+                f"{arch} uid {w.uid}: chunk 4 != chunk 32 tokens"
         segs = set()
         for l in lens:
             segs.update(chunk_schedule(l, 4))
@@ -941,7 +924,7 @@ def bench_prefill_smoke() -> None:
     # (c) TTFT under an adversary, deterministic/structural: a 24-token
     # prompt (6 chunks of 4) competes with short 4-token prompts.  Every
     # short request's FIRST token must land before the adversary's first
-    # — whole-prompt admission would stall the loop for the full foreign
+    # — a whole-prompt prefill would stall the loop for the full foreign
     # prefill instead.  The trace corroborates: one serve/prefill_chunk
     # per scheduled segment.
     cfg, model, params = dense
@@ -1188,7 +1171,7 @@ def bench_chaos_smoke(trace_path: str = "BENCH_ci_chaos_trace.jsonl",
         assert reasons[uid] == FinishReason.SHED, reasons
     # (c) zero-alloc through quarantine + re-admission
     assert eng.pool.stats.buffers_built == 1
-    assert eng._scratch_pool.stats.buffers_built == 1
+    assert eng._scratch_pool.stats.buffers_built == eng.prefill_lanes
     q = eng.metrics.counter("serving/quarantined").value
     rt = eng.metrics.counter("serving/retries").value
     sh = eng.metrics.counter("serving/shed").value
@@ -1307,7 +1290,7 @@ def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--serving", action="store_true",
                     help="run only the serving throughput benchmark "
-                         "(wave vs slot engine; the CI smoke invocation)")
+                         "(the CI smoke invocation)")
     ap.add_argument("--train", action="store_true",
                     help="run only the per-plan train-step benchmark")
     ap.add_argument("--stream-smoke", action="store_true",
@@ -1347,8 +1330,8 @@ def main() -> None:
                          "BENCH_ci_obs_profile.json)")
     ap.add_argument("--prefill-smoke", action="store_true",
                     help="run only the chunked-prefill smoke (asserts "
-                         "chunked-vs-whole-prompt greedy token identity on "
-                         "dense AND rwkv, one compiled executable per "
+                         "greedy token identity across two chunk lengths "
+                         "on dense AND rwkv, one compiled executable per "
                          "chunk segment length, short-request tokens "
                          "landing before a long-prompt adversary's first, "
                          "and the zero-alloc scratch-pool invariant; the "

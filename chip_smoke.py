@@ -350,9 +350,10 @@ def _serve_and_compare(cfg, args, out: dict) -> dict:
     del engine, warm, results
     gc.collect()
 
-    # first tokens: model.forward at batch 1 over exactly the prompt — the
-    # shape the admission prefill runs — so the two programs differ only in
-    # the prefill's cache writes and last-row head
+    # first tokens: model.forward at batch 1 over exactly the prompt — at
+    # a prompt of 128 tokens, the one 128-token segment of
+    # prefill_chunk_sample that admission runs — so the two programs differ
+    # only in the prefill's cache writes and last-row head
     first = jax.jit(lambda p, t: model.forward(
         p, {"tokens": t}, inference=True)[0][0, -1])
     t0 = time.perf_counter()

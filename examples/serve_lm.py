@@ -1,11 +1,8 @@
 """Serving example: a reduced qwen2 under the MobiRNN runtime policies —
 preallocated cache pools, load-aware plan dispatch (paper Fig 7, but for
-LLM decode) — comparing the two engines:
-
-  * wave (Engine):       lockstep batches, padded to the slowest request;
-  * slot (SlotEngine):   slot-resident continuous batching — per-lane
-                         admission/retirement over one preallocated cache,
-                         tokens streamed per tick.
+LLM decode) — through ``SlotEngine``: slot-resident continuous batching,
+per-lane admission/retirement over one preallocated cache, tokens streamed
+per tick.
 
   PYTHONPATH=src python examples/serve_lm.py
   PYTHONPATH=src python examples/serve_lm.py --trace serve_trace.jsonl
@@ -34,13 +31,13 @@ from repro.configs import get_arch
 from repro.core.scheduler import SyntheticLoadSensor
 from repro.models import registry
 from repro.partitioning import split
-from repro.serving import (Engine, EngineConfig, FaultPlan, QueueFull,
-                           Request, SlotEngine)
+from repro.serving import (EngineConfig, FaultPlan, QueueFull, Request,
+                           SlotEngine)
 
 
 def make_requests(cfg, rng):
     # ragged on purpose: mixed prompt lengths, 8x max_new spread — the
-    # workload where continuous batching beats waves
+    # workload where continuous batching beats lockstep batches
     lens = [8, 12, 6, 16, 8, 12, 6, 16, 8, 12, 6, 16]
     news = [2, 16, 4, 8, 16, 2, 8, 4, 16, 2, 4, 8]
     return [Request(i, rng.integers(0, cfg.vocab, (l,)).astype(np.int32),
@@ -147,24 +144,18 @@ def main() -> None:
     n_tok = sum(r.max_new_tokens for r in reqs)
 
     sensor = SyntheticLoadSensor(0.0)
-    wave = Engine(model, params, sensor=sensor, config=EngineConfig(
-        n_slots=4, max_seq=64, pool_capacity=2))
     slot = SlotEngine(model, params, sensor=sensor, config=EngineConfig(
         n_slots=4, max_seq=64, queue_capacity=8))
-
-    wave.serve(reqs)                   # compile both engines once so the
-    slot.serve(reqs)                   # printed rows are steady-state
-
+    slot.serve(reqs)        # compile once so the printed rows are steady
     for load in (0.0, 0.85):
         sensor.value = load
-        for name, engine in (("wave", wave), ("slot", slot)):
-            t0 = time.time()
-            results = engine.serve(reqs)
-            wall = time.time() - t0
-            plans = {p for r in results for p in r.plan_decisions}
-            print(f"load={load:.0%} {name}: {len(results)} requests, "
-                  f"{n_tok} tokens, {n_tok / wall:.1f} tok/s, "
-                  f"plans used: {plans}")
+        t0 = time.time()
+        results = slot.serve(reqs)
+        wall = time.time() - t0
+        plans = {p for r in results for p in r.plan_decisions}
+        print(f"load={load:.0%}: {len(results)} requests, "
+              f"{n_tok} tokens, {n_tok / wall:.1f} tok/s, "
+              f"plans used: {plans}")
 
     # streaming: tokens surface per tick, not when the whole batch drains;
     # TTFT is measured by the engine itself (submit -> first token on

@@ -22,7 +22,7 @@ from repro.configs import get_arch
 from repro.core.scheduler import SyntheticLoadSensor
 from repro.models import registry
 from repro.partitioning import split
-from repro.serving import Engine, EngineConfig, Request, SlotEngine
+from repro.serving import EngineConfig, Request, SlotEngine
 from repro.serving.slots import FinishReason
 
 #: terminal reasons that mean the engine failed a request (vs. ran it out)
@@ -37,9 +37,6 @@ def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--max-new", type=int, default=8)
     ap.add_argument("--batch-size", type=int, default=4)
-    ap.add_argument("--engine", choices=("wave", "slot"), default="slot",
-                    help="wave = lockstep batches; slot = slot-resident "
-                         "continuous batching (default)")
     ap.add_argument("--load", type=float, default=0.0,
                     help="injected accelerator load in [0,1] (paper Fig 7)")
     ap.add_argument("--seed", type=int, default=0)
@@ -64,12 +61,12 @@ def make_requests(cfg, args: argparse.Namespace) -> list[Request]:
             for i in range(args.requests)]
 
 
-def make_engine(model: registry.Model, params, args: argparse.Namespace):
+def make_engine(model: registry.Model, params, args: argparse.Namespace
+                ) -> SlotEngine:
     config = EngineConfig(n_slots=args.batch_size,
                           max_seq=args.prompt_len + args.max_new + 1,
                           queue_capacity=max(args.requests, 1))
-    engine_cls = SlotEngine if args.engine == "slot" else Engine
-    return engine_cls(model, params, config=config,
+    return SlotEngine(model, params, config=config,
                       sensor=SyntheticLoadSensor(args.load))
 
 

@@ -175,7 +175,7 @@ def chunk_prefill_attention(p: dict, x: jax.Array, cache: dict,
     never evicts a position a later query still needs — i.e. for
     sliding-window layouts only while the whole prompt fits the ring
     (prompt_len <= S_c); the serving engine routes longer windowed
-    prompts through the whole-prompt path instead.
+    prompts through a one-segment whole-prompt prefill instead.
 
     Returns (attention output (B, L, d), cache writes dict).
     """
@@ -295,8 +295,8 @@ def decode_attention(p: dict, x: jax.Array, cache: dict, pos: jax.Array,
                      cfg: ModelConfig) -> tuple[jax.Array, dict]:
     """One-token cached attention.  x: (B, 1, d); cache slot arrays
     (B, S_c, Hkv, dh); pos: absolute position of this token — a scalar
-    (all lanes in lockstep, the wave engine) or a (B,) vector (each lane
-    at its own position, the slot-resident continuous-batching engine).
+    (all lanes in lockstep) or a (B,) vector (each lane at its own
+    position, the slot-resident continuous-batching engine).
     The scalar case is exactly the vector case with every lane equal, so
     one code path serves both.
 

@@ -1,4 +1,4 @@
-from repro.serving.engine import Engine, EngineConfig, SlotEngine
+from repro.serving.engine import EngineConfig, SlotEngine
 from repro.serving.faults import (FaultInjector, FaultPlan, InjectedFault,
                                   LanePoison, PrefillFault, QueueFlood,
                                   SlowTick)
@@ -7,7 +7,7 @@ from repro.serving.slots import (FINISH_REASONS, FinishReason, PrefillLane,
                                  Slot, SlotManager, TokenEvent,
                                  chunk_schedule)
 
-__all__ = ["Engine", "EngineConfig", "SlotEngine", "Request", "Result",
+__all__ = ["EngineConfig", "SlotEngine", "Request", "Result",
            "RequestQueue", "QueueFull", "Slot", "SlotManager", "TokenEvent",
            "PrefillLane", "chunk_schedule",
            "FinishReason", "FINISH_REASONS", "FaultPlan", "FaultInjector",

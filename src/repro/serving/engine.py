@@ -1,41 +1,32 @@
-"""Serving engines with MobiRNN-style runtime policies.
+"""The serving engine: slot-resident continuous batching with MobiRNN-style
+runtime policies.
 
 The paper mechanisms are first-class here:
-  * preallocated state pools (core/state.StatePool) — decode caches are
-    built once and reset in place through donated jits; no allocation on the
-    serving path, pool exhaustion = explicit backpressure;
-  * load-aware dispatch (core/scheduler.Scheduler) — multiple decode plans
-    are registered and the predicted-fastest under current load runs each
+  * preallocated state pools (core/state.StatePool) — the resident decode
+    cache and the B=1 prefill scratch buffers are built once and reset in
+    place through donated jits; no allocation on the serving path, pool
+    exhaustion = explicit backpressure;
+  * load-aware dispatch (core/scheduler.Scheduler) — decode plans are
+    registered and the predicted-fastest under current load runs each
     tick (paper Fig 7);
   * fixed-shape batching — the decode step has one shape for the life of
     the engine.
 
-Two engines share that substrate:
+``SlotEngine`` turns the batch axis into B independent slots over one
+preallocated cache (serving/slots.py): requests wait in a bounded queue,
+prefill through ``PrefillLane`` state machines at most one segment between
+decode ticks, and land in a free slot once fully prefilled; decode runs one
+fused masked step across all lanes per tick, and retirement resets just
+that lane.  Tokens stream out per tick (``stream``/``on_token``).
 
-``Engine`` — the coarse WAVE engine: requests are packed into lockstep
-waves of ``batch_size``; every request pads to the longest prompt and the
-longest ``max_new_tokens`` in its wave.  Short waves are padded with
-zero-length dummy requests (an inactive lane, not a duplicated real
-request).  Kept as the baseline the benchmarks compare against.
-
-``SlotEngine`` — slot-resident CONTINUOUS batching (serving/slots.py): the
-batch axis is B independent slots over one preallocated cache; requests are
-admitted from a bounded queue into free slots at step granularity, decode
-runs one fused masked step across all lanes per tick, and retirement resets
-just that lane and immediately admits the next request.  Tokens stream out
-per tick (``stream``/``on_token``) instead of arriving all at once.  This
-is the engine the ROADMAP's heavy-traffic north star builds on.
-
-Both engines are modality-generic: they serve any registry.Model whose
-config family is text-like (dense/moe/ssm/hybrid/vlm/audio all decode
-token ids).
+The engine is modality-generic: it serves any registry.Model whose config
+family is text-like (dense/moe/ssm/hybrid/vlm/audio all decode token ids).
 """
 from __future__ import annotations
 
 import collections
 import dataclasses
 import time
-import warnings
 from typing import Any, Callable, Iterator
 
 import jax
@@ -54,38 +45,24 @@ from repro.serving.slots import (FinishReason, PrefillLane, QueueFull,
                                  TokenEvent, chunk_schedule)
 from repro import steps as steps_lib
 
+#: admission-prefill chunk length when ``EngineConfig.prefill_chunk_len``
+#: is unset
+CHUNK_LEN = 256
+
 
 @dataclasses.dataclass
 class EngineConfig:
-    """The consolidated construction surface for both engines — every
-    queue/retry/ladder/fault/chunk knob in one dataclass instead of
-    sprawled across ``Engine``/``SlotEngine`` kwargs.  Engines take
-    ``config=EngineConfig(...)``; the old per-engine kwargs remain as
-    deprecated aliases (DeprecationWarning) so downstream callers migrate
-    at their own pace.  Unused knobs are simply ignored by the engine that
-    does not implement them (``pool_capacity`` is a wave knob — the slot
-    engine always runs ONE resident cache; ``queue_capacity``/retry/
-    ladder/chunk knobs are slot knobs).
-
-    Chunked prefill (``prefill_chunk_len``):
-      * ``None`` (default) keeps whole-prompt admission — one B=1 prefill
-        dispatch per request, one compiled executable per DISTINCT prompt
-        length, and one long prompt stalls every resident lane's decode
-        tick for its whole prefill;
-      * an int enables chunk-interleaved admission: prompts prefill
-        through up-to-``prefill_lanes`` PrefillLane state machines, at
-        most ONE fixed-shape chunk between decode ticks, and admit into a
-        slot only when fully prefilled.  Greedy outputs are token-
-        identical to whole-prompt prefill — chunking changes scheduling,
-        not math.
-    """
+    """Every queue/retry/ladder/fault/chunk knob of ``SlotEngine`` in one
+    dataclass; the engine takes ``config=EngineConfig(...)``.  The
+    admission knobs (``prefill_chunk_len``, ``prefill_lanes``) are
+    described on ``SlotEngine``."""
     n_slots: int = 4
     max_seq: int = 128
     queue_capacity: int = 16
-    pool_capacity: int = 2
-    #: admission-prefill chunk length (None = whole-prompt admission)
+    #: admission-prefill chunk length; None = ``CHUNK_LEN``, lowered to
+    #: the longest prompt chunking serves exactly when that is shorter
     prefill_chunk_len: int | None = None
-    #: concurrent partially-prefilled requests (chunked mode only)
+    #: concurrent partially-prefilled requests
     prefill_lanes: int = 2
     retry_budget: int = 0
     retry_backoff_s: float = 0.0
@@ -96,70 +73,57 @@ class EngineConfig:
     ladder: list[str] | None = None
     faults: faults_lib.FaultPlan | None = None
 
-    @property
-    def batch_size(self) -> int:
-        """Wave-engine naming for the batch axis (== ``n_slots``)."""
-        return self.n_slots
 
+class SlotEngine:
+    """Slot-resident continuous batching (see serving/slots.py docstring).
 
-#: deprecated per-engine kwarg -> EngineConfig field
-_WAVE_ALIASES = {"batch_size": "n_slots", "max_seq": "max_seq",
-                 "pool_capacity": "pool_capacity"}
-_SLOT_ALIASES = {k: k for k in (
-    "n_slots", "max_seq", "queue_capacity", "faults", "retry_budget",
-    "retry_backoff_s", "tick_slo_s", "slo_breach_ticks",
-    "slo_recover_ticks", "shed_margin", "ladder")}
+    Greedy outputs are token-identical to an unpadded per-request greedy
+    decode: every prompt prefills through its own B=1 scratch cache, and
+    lanes never interact — per-lane positions keep attention exact, and
+    rwkv/mamba/MoE-decode paths are lane-independent by construction.
 
+    Admission is CHUNK-INTERLEAVED: up to ``prefill_lanes`` PrefillLane
+    state machines each prefill one prompt through fixed-shape segments
+    (slots.chunk_schedule), the tick loop advances at most ONE segment
+    between decode ticks (round-robin across lanes), and a lane admits
+    into a slot only when fully prefilled.  Resident lanes therefore never
+    stall for more than one segment on a long-prompt admission, and the
+    compiled prefill shapes are one per segment length ({chunk_len} plus
+    descending powers of two for remainders).  Chunking changes
+    scheduling, not math: greedy outputs are token-identical to a
+    whole-prompt prefill.  The prompts chunking cannot serve exactly —
+    vision-token prompts, and windowed prompts longer than the KV ring —
+    take a one-segment lane through the whole-prompt program instead
+    (``_schedule``).
+    """
 
-def _resolve_config(cls_name: str, config: EngineConfig | None,
-                    legacy: dict, aliases: dict) -> EngineConfig:
-    """Fold an engine's deprecated construction kwargs into EngineConfig.
+    #: smoothing for the observed tick-latency EMA the shed predicate and
+    #: watchdog read (matches core.scheduler.Plan.ema)
+    TICK_EMA = 0.3
 
-    Exactly one spelling per call: legacy kwargs warn (DeprecationWarning
-    pointing at the caller) and build a fresh config through the alias
-    map; mixing them with an explicit ``config`` is ambiguous and raises."""
-    if not legacy:
-        return config if config is not None else EngineConfig()
-    unknown = sorted(set(legacy) - set(aliases))
-    if unknown:
-        raise TypeError(
-            f"{cls_name}: unexpected keyword argument(s) {unknown}")
-    if config is not None:
-        raise ValueError(
-            f"{cls_name}: pass config=EngineConfig(...) OR the deprecated "
-            f"kwargs {sorted(legacy)}, not both")
-    warnings.warn(
-        f"{cls_name}({', '.join(sorted(legacy))}) kwargs are deprecated; "
-        "pass config=EngineConfig(...)", DeprecationWarning, stacklevel=3)
-    return EngineConfig(**{aliases[k]: v for k, v in legacy.items()})
-
-
-class _EngineBase:
-    """Shared substrate: cache pool, prefill jit, decode-plan scheduler."""
-
-    def __init__(self, model: Model, params: Any, *, batch_size: int,
-                 max_seq: int, pool_capacity: int, sensor,
-                 extra_plans: dict[str, Callable] | None, per_lane_pos: bool):
+    def __init__(self, model: Model, params: Any, *,
+                 config: EngineConfig | None = None, sensor=None,
+                 extra_plans: dict[str, Callable] | None = None,
+                 clock: Callable[[], float] = None):
+        """All queue/retry/ladder/fault/chunk knobs live on ``config``
+        (EngineConfig, see its docstring).  ``sensor``/``extra_plans``/
+        ``clock`` are kwargs of their own — they are collaborator
+        objects, not configuration."""
+        config = config if config is not None else EngineConfig()
+        self.config = config
         self.model = model
         self.cfg = model.cfg
         self.params = params
-        self.batch_size = batch_size
-        self.max_seq = max_seq
+        self.n_slots = n_slots = config.n_slots
+        self.max_seq = max_seq = config.max_seq
+        self.clock = clock or time.monotonic
 
-        cache_annot = jax.eval_shape(
-            lambda: model.init_cache(batch_size, max_seq))
-        cache_abs, _ = split(cache_annot)
-        if per_lane_pos:
-            # continuous batching: each lane decodes at its own position
-            cache_abs = dict(cache_abs, pos=jax.ShapeDtypeStruct(
-                (batch_size,), jnp.int32))
-        self.pool = StatePool(cache_abs, capacity=pool_capacity)
-
-        # shape-polymorphic: the same jit serves (B, S) wave prefills and
-        # (1, S) per-slot admission prefills (one compile per shape)
-        self._prefill = jax.jit(
-            lambda p, c, b: steps_lib.prefill_step(self.cfg, p, c, b),
-            donate_argnums=(1,))
+        # ONE resident cache; each lane decodes at its own position
+        cache_abs, _ = split(jax.eval_shape(
+            lambda: model.init_cache(n_slots, max_seq)))
+        cache_abs = dict(cache_abs, pos=jax.ShapeDtypeStruct(
+            (n_slots,), jnp.int32))
+        self.pool = StatePool(cache_abs, capacity=1)
 
         self.scheduler = Scheduler(sensor or SyntheticLoadSensor(0.0))
         for name, fn in self._decode_plans(extra_plans or {}).items():
@@ -171,162 +135,6 @@ class _EngineBase:
         # serving invariant (tests assert buffers_built stays at capacity
         # with metrics enabled); tracing stays opt-in via obs.trace
         self.metrics = Metrics()
-
-    def _decode_plans(self, extra: dict[str, Callable]
-                      ) -> dict[str, Callable]:
-        raise NotImplementedError
-
-    def _prefill_batch(self, toks: np.ndarray) -> dict:
-        batch = {"tokens": jnp.asarray(toks)}
-        if self.cfg.n_vis_tokens:
-            batch["vis_embeds"] = jnp.zeros(
-                (toks.shape[0], self.cfg.n_vis_tokens, self.cfg.vis_dim),
-                jnp.dtype(self.cfg.dtype))
-        return batch
-
-
-# ---------------------------------------------------------------------------
-# Wave engine (baseline)
-# ---------------------------------------------------------------------------
-class Engine(_EngineBase):
-    """Lockstep wave engine — the coarse-batching baseline."""
-
-    def __init__(self, model: Model, params: Any, *,
-                 config: EngineConfig | None = None, sensor=None,
-                 extra_plans: dict[str, Callable] | None = None, **legacy):
-        config = _resolve_config("Engine", config, legacy, _WAVE_ALIASES)
-        self.config = config
-        super().__init__(model, params, batch_size=config.n_slots,
-                         max_seq=config.max_seq,
-                         pool_capacity=config.pool_capacity,
-                         sensor=sensor, extra_plans=extra_plans,
-                         per_lane_pos=False)
-
-    def _decode_plans(self, extra: dict[str, Callable]
-                      ) -> dict[str, Callable]:
-        plans = {"decode/base":
-                 lambda p, c, b: steps_lib.decode_step(self.cfg, p, c, b)}
-        plans.update(extra)
-        return plans
-
-    # ------------------------------------------------------------------
-    def _dummy_request(self) -> Request:
-        """Zero-length, zero-token filler for ragged wave tails — an
-        inactive lane, NOT a duplicate of a real request."""
-        shape = ((self.cfg.n_codebooks, 0) if self.cfg.n_codebooks
-                 else (0,))
-        return Request(uid=-1, prompt=np.zeros(shape, np.int32),
-                       max_new_tokens=0)
-
-    def _pad_prompts(self, reqs: list[Request]) -> tuple[np.ndarray, int]:
-        lens = [r.prompt.shape[-1] for r in reqs]
-        s = max(lens)
-        shape = ((self.batch_size, self.cfg.n_codebooks, s)
-                 if self.cfg.n_codebooks else (self.batch_size, s))
-        toks = np.zeros(shape, np.int32)
-        for i, r in enumerate(reqs):
-            toks[i, ..., s - r.prompt.shape[-1]:] = r.prompt  # left-pad
-        return toks, s
-
-    def serve(self, requests: list[Request]) -> list[Result]:
-        """Serve all requests in fixed-shape waves of `batch_size`."""
-        results: list[Result] = []
-        for i in range(0, len(requests), self.batch_size):
-            wave = requests[i:i + self.batch_size]
-            pad = self.batch_size - len(wave)
-            wave_padded = wave + [self._dummy_request()] * pad
-            results.extend(self._serve_wave(wave_padded)[: len(wave)])
-        return results
-
-    def _serve_wave(self, reqs: list[Request]) -> list[Result]:
-        cache = self.pool.checkout()
-        toks, _ = self._pad_prompts(reqs)
-        batch = self._prefill_batch(toks)
-
-        t0 = time.perf_counter()
-        logits, cache = jax.block_until_ready(
-            self._prefill(self.params, cache, batch))
-        t_prefill = time.perf_counter() - t0
-
-        max_new = max(r.max_new_tokens for r in reqs)
-        outs = []
-        decisions = []
-        tracer = trace_lib.get_tracer()
-        wave_span = (tracer.span("serve/wave", n_reqs=len(reqs),
-                                 max_new=max_new, prefill_s=t_prefill)
-                     if tracer.enabled else trace_lib.NULL_SPAN)
-        # prefill logits keep a singleton seq axis before the vocab dim
-        tok = steps_lib.greedy_sample(logits)[..., 0]
-        t0 = time.perf_counter()
-        with wave_span:
-            for _ in range(max_new):
-                outs.append(np.asarray(tok))
-                d = self.scheduler.choose()
-                decisions.append(d.plan)
-                plan = self.scheduler.plans[d.plan]
-                t1 = time.perf_counter()
-                logits, cache = jax.block_until_ready(
-                    plan.fn(self.params, cache, {"tokens": tok}))
-                plan.observe(time.perf_counter() - t1, d.load)
-                tok = steps_lib.greedy_sample(logits)
-            t_decode = time.perf_counter() - t0
-            wave_span.set(decode_s=t_decode)
-        self.pool.give_back(cache)
-
-        # (B, [K,] max_new); toks[..., :0] covers an all-zero-budget wave
-        gen = (np.stack(outs, axis=-1) if outs else toks[..., :0])
-        return [Result(r.uid, gen[j, ..., :r.max_new_tokens], t_prefill,
-                       t_decode, decisions)
-                for j, r in enumerate(reqs)]
-
-
-# ---------------------------------------------------------------------------
-# Slot engine (continuous batching)
-# ---------------------------------------------------------------------------
-class SlotEngine(_EngineBase):
-    """Slot-resident continuous batching (see serving/slots.py docstring).
-
-    Greedy outputs are token-identical to an unpadded per-request reference
-    (the wave engine at batch_size=1): admission prefills each prompt at
-    its exact length through a B=1 scratch cache, and lanes never interact
-    — per-lane positions keep attention exact, and rwkv/mamba/MoE-decode
-    paths are lane-independent by construction.  Distinct prompt lengths
-    compile distinct prefill executables (bucket upstream if that matters).
-
-    With ``EngineConfig.prefill_chunk_len`` set, admission prefill is
-    CHUNK-INTERLEAVED instead: up to ``prefill_lanes`` PrefillLane state
-    machines each prefill one prompt through fixed-shape segments
-    (slots.chunk_schedule), the tick loop advances at most ONE chunk
-    between decode ticks (round-robin across lanes), and a lane admits
-    into a slot only when fully prefilled.  Resident lanes therefore
-    never stall for more than one chunk on a long-prompt admission — the
-    lockstep pathology whole-prompt admission readmits — while greedy
-    outputs stay token-identical to whole-prompt prefill and the compiled
-    prefill shapes collapse from one-per-prompt-length to one per segment
-    length ({chunk_len} plus descending powers of two for remainders).
-    """
-
-    #: smoothing for the observed tick-latency EMA the shed predicate and
-    #: watchdog read (matches core.scheduler.Plan.ema)
-    TICK_EMA = 0.3
-
-    def __init__(self, model: Model, params: Any, *,
-                 config: EngineConfig | None = None, sensor=None,
-                 extra_plans: dict[str, Callable] | None = None,
-                 clock: Callable[[], float] = None, **legacy):
-        """All queue/retry/ladder/fault/chunk knobs live on ``config``
-        (EngineConfig, see its docstring); the old per-engine kwargs are
-        accepted as deprecated aliases.  ``sensor``/``extra_plans``/
-        ``clock`` stay real kwargs — they are collaborator objects, not
-        configuration."""
-        config = _resolve_config("SlotEngine", config, legacy, _SLOT_ALIASES)
-        self.config = config
-        n_slots, max_seq = config.n_slots, config.max_seq
-        super().__init__(model, params, batch_size=n_slots, max_seq=max_seq,
-                         pool_capacity=1, sensor=sensor,
-                         extra_plans=extra_plans, per_lane_pos=True)
-        self.n_slots = n_slots
-        self.clock = clock or time.monotonic
         self.queue = RequestQueue(config.queue_capacity, clock=self.clock)
         # completed Results land here until the caller consumes them with
         # take_finished() — long-running submit()/stream() users must drain
@@ -336,52 +144,41 @@ class SlotEngine(_EngineBase):
         # clock; dropped at its first token or when it ends without one
         self._submitted: dict[int, float] = {}
 
-        # -- chunked prefill (admission interleaving) -----------------------
+        # -- admission prefill ----------------------------------------------
         w = self.cfg.sliding_window or 0
-        #: longest prompt the CHUNKED path serves token-identically: a
+        #: longest prompt chunked prefill serves token-identically: a
         #: windowed KV ring starts evicting once the prompt outruns the
         #: cache seq axis, and mid-chunk queries then see less in-window
         #: history than whole-prompt flash attention would give them.
-        #: Longer windowed prompts fall back to whole-prompt admission.
         self._chunk_safe_len = min(max_seq, w) if w else max_seq
-        self._chunk_len = config.prefill_chunk_len
+        chunk = config.prefill_chunk_len
+        if chunk is None:
+            chunk = min(CHUNK_LEN, self._chunk_safe_len)
+        elif not 0 < chunk <= self._chunk_safe_len:
+            raise ValueError(
+                f"prefill_chunk_len {chunk} outside (0, "
+                f"{self._chunk_safe_len}] — chunks longer than the "
+                "cache seq axis would scatter duplicate ring slots")
+        self._chunk_len = chunk
         self.prefill_lanes = config.prefill_lanes
-        chunked = self._chunk_len is not None
-        if chunked:
-            if self.cfg.n_vis_tokens:
-                raise ValueError(
-                    "chunked prefill cannot serve vis-token prompts (the "
-                    "vision prefix is not sliceable); keep "
-                    "prefill_chunk_len=None")
-            if not 0 < self._chunk_len <= self._chunk_safe_len:
-                raise ValueError(
-                    f"prefill_chunk_len {self._chunk_len} outside (0, "
-                    f"{self._chunk_safe_len}] — chunks longer than the "
-                    "cache seq axis would scatter duplicate ring slots")
-            if self.prefill_lanes < 1:
-                raise ValueError(
-                    f"prefill_lanes {self.prefill_lanes} must be >= 1")
+        if self.prefill_lanes < 1:
+            raise ValueError(
+                f"prefill_lanes {self.prefill_lanes} must be >= 1")
 
-        # B=1 scratch the admission prefill runs through (donated per
-        # dispatch).  Whole-prompt mode keeps ONE permanently checked-out
-        # buffer; chunked mode pools ``prefill_lanes`` of them (one per
-        # concurrent PrefillLane, checked out at lane start and returned —
-        # zeroed through the pool's donated reset — at admission, abort or
-        # failure), plus the persistent whole-prompt buffer when windowed
-        # fallbacks are possible.  Either way the pool is built ONCE:
-        # ``buffers_built`` stays at capacity for the life of the engine.
+        # one B=1 scratch per concurrent PrefillLane (donated per
+        # dispatch), checked out at lane start and returned — zeroed
+        # through the pool's donated reset — at admission, abort or
+        # failure.  The pool is built ONCE: ``buffers_built`` stays at
+        # ``prefill_lanes`` for the life of the engine.
         scratch_abs, _ = split(jax.eval_shape(
             lambda: model.init_cache(1, max_seq)))
         self._scratch_abs = scratch_abs
-        self._fallback = chunked and bool(w) and self._chunk_safe_len < max_seq
-        self._scratch_pool = StatePool(
-            scratch_abs, capacity=(self.prefill_lanes + int(self._fallback)
-                                   if chunked else 1))
-        self._scratch = (self._scratch_pool.checkout()
-                         if not chunked or self._fallback else None)
+        self._scratch_pool = StatePool(scratch_abs,
+                                       capacity=self.prefill_lanes)
 
         def prefill_sample(p, c, b):
-            # zero the donated scratch first — rwkv/mamba prefill consumes
+            # the whole-prompt program of a one-segment lane: zero the
+            # donated scratch first — rwkv/mamba prefill consumes
             # the cache as its initial state, so a previous occupant's
             # state must not leak into the next prompt — then sample the
             # prompt's first greedy token, all in one dispatch
@@ -407,8 +204,7 @@ class SlotEngine(_EngineBase):
             self.metrics.counter(name)
         self.metrics.histogram("serving/ttft_s")
         self.metrics.histogram("serving/tbt_s")
-        if chunked:
-            self.metrics.histogram("serving/prefill_chunk_s")
+        self.metrics.histogram("serving/prefill_chunk_s")
 
         token_tail = ((self.cfg.n_codebooks,) if self.cfg.n_codebooks
                       else ())
@@ -416,7 +212,7 @@ class SlotEngine(_EngineBase):
         self._prefill_chunk = jax.jit(prefill_chunk_sample,
                                       donate_argnums=(1,))
         # device-resident chunk-0 flags, uploaded once and reused — the
-        # chunked path keeps the no-per-dispatch-upload property
+        # chunk dispatch keeps the no-per-dispatch-upload property
         self._first_true = jnp.asarray(True)
         self._first_false = jnp.asarray(False)
         self._lanes: list[PrefillLane] = []
@@ -524,27 +320,6 @@ class SlotEngine(_EngineBase):
                             prompt_len=prompt_len, chunks=chunks)
         return ttft_s, queue_s
 
-    def _admit_one(self, index: int, req: Request) -> TokenEvent:
-        prompt = np.asarray(req.prompt, np.int32)
-        tracer = trace_lib.get_tracer()
-        t_start = tracer.clock()
-        t0 = time.perf_counter()
-        tok0, self._scratch = self._prefill_sample(
-            self.params, self._scratch,
-            self._prefill_batch(prompt.reshape((1,) + prompt.shape)))
-        tok0 = tok0[0]                       # () or (K,), device array
-        prefill_s = time.perf_counter() - t0
-        tok0_np = np.asarray(tok0, np.int32)  # blocks: token host-visible
-        slot = self.manager.admit(index, req, self._scratch, tok0, prefill_s)
-        slot.ttft_s, slot.queue_s = self._first_token(
-            req, t_start, int(prompt.shape[-1]), chunks=1)
-        if tracer.enabled:
-            tracer.event("serve/admit", uid=req.uid, slot=index,
-                         prompt_len=int(prompt.shape[-1]),
-                         prefill_s=prefill_s, ttft_s=slot.ttft_s)
-        return TokenEvent(req.uid, tok0_np, 0,
-                          done=(req.max_new_tokens <= 1))
-
     # -- fault-tolerance plumbing --------------------------------------
     def _terminal(self, req: Request, reason: str) -> TokenEvent:
         """Publish a tokenless terminal Result (queue expiry, dead-on-
@@ -589,41 +364,40 @@ class SlotEngine(_EngineBase):
         return (FinishReason.RETRIES_EXHAUSTED if self.retry_budget > 0
                 else FinishReason.ERROR)
 
-    def _prefill_failed(self, req: Request, now: float, err: Exception
-                        ) -> Iterator[TokenEvent]:
-        """Containment for an admission prefill that raised: emit the
-        serve/fault event, then retry or terminate the request."""
-        injected = isinstance(err, faults_lib.InjectedFault)
-        if not injected and self._scratch is not None and any(
-                getattr(a, "is_deleted", lambda: False)()
-                for a in jax.tree.leaves(self._scratch)):
-            # a REAL prefill exception may have consumed the donated
-            # scratch mid-dispatch; rebuild it so the next admission still
-            # works.  Injected faults raise before the dispatch and never
-            # take this path, so chaos runs stay zero-allocation.
-            self._scratch = make_buffer(self._scratch_abs)
-        tracer = trace_lib.get_tracer()
-        if tracer.enabled:
-            tracer.event("serve/fault", kind="prefill", uid=req.uid,
-                         injected=injected, error=repr(err))
-        reason = self._fail_or_retry(req, now)
-        if reason is not None:
-            yield self._terminal(req, reason)
+    # -- admission -------------------------------------------------------
+    def _prefill_batch(self, toks: np.ndarray) -> dict:
+        batch = {"tokens": jnp.asarray(toks)}
+        if self.cfg.n_vis_tokens:
+            batch["vis_embeds"] = jnp.zeros(
+                (toks.shape[0], self.cfg.n_vis_tokens, self.cfg.vis_dim),
+                jnp.dtype(self.cfg.dtype))
+        return batch
 
-    # -- chunked admission (the tentpole) ------------------------------
+    def _schedule(self, prompt_len: int) -> tuple[list[int], bool]:
+        """A prompt's prefill segments, and whether its lane runs the
+        whole-prompt program.  Chunking is exact except for vision-token
+        prompts (the vision prefix is prepended whole at embed time) and
+        windowed prompts longer than the KV ring; those prefill as ONE
+        segment through ``_prefill_sample`` — and eat the full stall."""
+        if self.cfg.n_vis_tokens or prompt_len > self._chunk_safe_len:
+            return [prompt_len], True
+        return chunk_schedule(prompt_len, self._chunk_len), False
+
     def _lane_failed(self, lane: PrefillLane, now: float, err: Exception
                      ) -> Iterator[TokenEvent]:
-        """Containment for a chunked-prefill attempt that raised: the
-        lane's PARTIAL state is discarded (its scratch returns to the pool
-        through the donated zeroing reset), so a retry restarts from chunk
-        0 with a clean cache — token-identical to an unfaulted admission."""
+        """Containment for a prefill attempt that raised: the lane's
+        PARTIAL state is discarded (its scratch returns to the pool through
+        the donated zeroing reset), so a retry restarts from chunk 0 with
+        a clean cache — token-identical to an unfaulted admission."""
         injected = isinstance(err, faults_lib.InjectedFault)
         cache = lane.cache
         if not injected and any(
                 getattr(a, "is_deleted", lambda: False)()
                 for a in jax.tree.leaves(cache)):
-            # same rebuild rule as _prefill_failed: only a REAL exception
-            # can strand a consumed donated buffer
+            # a REAL exception may have consumed the donated scratch
+            # mid-dispatch; rebuild it so the pool stays whole.  Injected
+            # faults raise before the dispatch and never take this path,
+            # so chaos runs stay zero-allocation.
             cache = make_buffer(self._scratch_abs)
         self._scratch_pool.give_back(cache)
         tracer = trace_lib.get_tracer()
@@ -637,7 +411,7 @@ class SlotEngine(_EngineBase):
 
     def _advance_lane(self, lane: PrefillLane, now: float
                       ) -> Iterator[TokenEvent]:
-        """Run ONE prefill chunk for ``lane``; on the final chunk, admit
+        """Run ONE prefill segment for ``lane``; on the last one, admit
         the fully-prefilled request into a free slot (the invariant
         ``len(self._lanes) <= free slots`` guarantees one exists — slots
         are only ever occupied BY lane admission while lanes are live)."""
@@ -664,8 +438,12 @@ class SlotEngine(_EngineBase):
                   if on else null):
                 batch = self._prefill_batch(toks.reshape((1,) + toks.shape))
                 with tracer.span("serve/chunk/dispatch") if on else null:
-                    tok, lane.cache = self._prefill_chunk(
-                        self.params, lane.cache, batch, first)
+                    if lane.whole:
+                        tok, lane.cache = self._prefill_sample(
+                            self.params, lane.cache, batch)
+                    else:
+                        tok, lane.cache = self._prefill_chunk(
+                            self.params, lane.cache, batch, first)
                 with tracer.span("serve/chunk/sync") if on else null:
                     tok = jax.block_until_ready(tok)
                 chunk_s = time.perf_counter() - t0
@@ -707,13 +485,12 @@ class SlotEngine(_EngineBase):
             self._finish(mgr.retire(idx))
 
     def _admit_chunked(self, now: float, refill) -> Iterator[TokenEvent]:
-        """One scheduling round of chunk-interleaved admission: abort
-        deadline-expired lanes, start new lanes while scratch buffers AND
-        target slots are both free, then advance at most ONE chunk total
-        (round-robin across live lanes) before the decode tick runs."""
+        """One scheduling round of admission: abort deadline-expired
+        lanes, start new lanes while scratch buffers AND target slots are
+        both free, then advance at most ONE segment total (round-robin
+        across live lanes) before the decode tick runs."""
         mgr = self.manager
         metrics = self.metrics
-        inj = self.injector
         tracer = trace_lib.get_tracer()
 
         # partially-prefilled requests past their deadline abort here —
@@ -739,28 +516,11 @@ class SlotEngine(_EngineBase):
                 yield self._zero_budget(req)
                 continue
             prompt = np.asarray(req.prompt, np.int32)
-            if prompt.shape[-1] > self._chunk_safe_len:
-                # windowed prompt past the cache seq axis: chunked replay
-                # through the ring is not token-identical, so this one
-                # admission takes the legacy whole-prompt path (and eats
-                # the full stall — the documented trade)
-                idx = mgr.free_indices()[0]
-                try:
-                    if inj is not None and inj.take_prefill_fault(req.uid):
-                        raise faults_lib.InjectedFault(
-                            f"injected prefill fault, uid={req.uid}")
-                    ev = self._admit_one(idx, req)
-                except Exception as err:
-                    yield from self._prefill_failed(req, now, err)
-                    continue
-                yield ev
-                if ev.done:
-                    self._finish(mgr.retire(idx))
-                continue
+            schedule, whole = self._schedule(prompt.shape[-1])
             self._lanes.append(PrefillLane(
                 request=req, cache=self._scratch_pool.checkout(),
-                schedule=chunk_schedule(prompt.shape[-1], self._chunk_len),
-                prompt=prompt, t_start=tracer.clock()))
+                schedule=schedule, whole=whole, prompt=prompt,
+                t_start=tracer.clock()))
             if tracer.enabled:
                 tracer.event("serve/prefill_start", uid=req.uid,
                              prompt_len=int(prompt.shape[-1]),
@@ -896,35 +656,9 @@ class SlotEngine(_EngineBase):
                                      tick_ema_s=self._tick_ema)
                     yield self._terminal(req, FinishReason.SHED)
 
-            # step-granular admission — chunk-interleaved (at most one
-            # prefill chunk before the decode tick) or whole-prompt
-            if self._chunk_len is not None:
-                yield from self._admit_chunked(now, refill_and_expire)
-            else:
-                for idx in mgr.free_indices():
-                    yield from refill_and_expire()
-                    req = self.queue.pop()
-                    if req is None:
-                        break
-                    if req.max_new_tokens <= 0:
-                        # zero-budget request: complete without a lane
-                        yield self._zero_budget(req)
-                        continue
-                    try:
-                        if (inj is not None
-                                and inj.take_prefill_fault(req.uid)):
-                            # raised BEFORE the dispatch: the donated
-                            # scratch is untouched, exactly the guarantee
-                            # InjectedFault documents
-                            raise faults_lib.InjectedFault(
-                                f"injected prefill fault, uid={req.uid}")
-                        ev = self._admit_one(idx, req)
-                    except Exception as err:  # containment: never escapes
-                        yield from self._prefill_failed(req, now, err)
-                        continue
-                    yield ev
-                    if ev.done:
-                        self._finish(mgr.retire(idx))
+            # step-granular admission: at most one prefill segment before
+            # the decode tick
+            yield from self._admit_chunked(now, refill_and_expire)
 
             queue_depth = len(self.queue)
             occupied = sum(1 for s in mgr.slots if s.occupied)

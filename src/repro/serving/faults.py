@@ -55,11 +55,10 @@ class PrefillFault:
     """Fail one prefill ATTEMPT for ``uid``.  One-shot and per-attempt:
     a retry succeeds unless another PrefillFault for the same uid remains.
 
-    ``chunk`` refines WHERE in a chunked admission the attempt fails:
-    ``None`` (default, and the whole-prompt path's only meaning) fires at
-    the next attempt whatever its chunk index; ``chunk=k`` fires at the
-    attempt that would run chunk ``k``, i.e. after ``k`` chunks of scratch
-    state have been filled.  Either way the fault raises BEFORE dispatch,
+    ``chunk`` refines WHERE in an admission the attempt fails: ``None``
+    (default) fires at the next attempt whatever its chunk index;
+    ``chunk=k`` fires at the attempt that would run chunk ``k``, i.e.
+    after ``k`` chunks of scratch state have been filled.  Either way the fault raises BEFORE dispatch,
     the lane's partial prefill state is discarded (donated zeroing reset),
     and a retry restarts from chunk 0 — token-identical to an unfaulted
     admission (asserted by tests/test_serving_faults.py)."""
@@ -203,9 +202,9 @@ class FaultInjector:
     def take_prefill_fault(self, uid: int, chunk: int = 0) -> bool:
         """True exactly once per scheduled PrefillFault for ``uid`` —
         consumed per ATTEMPT, not per request.  ``chunk`` is the chunk
-        index this attempt would run (0 for the whole-prompt path, which
-        has exactly one attempt per admission); a scheduled fault with
-        ``chunk=None`` matches any attempt, ``chunk=k`` only the k-th."""
+        index this attempt would run (0 for a one-segment lane); a
+        scheduled fault with ``chunk=None`` matches any attempt,
+        ``chunk=k`` only the k-th."""
         scheduled = self._prefill.get(uid)
         if not scheduled:
             return False

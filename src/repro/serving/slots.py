@@ -1,7 +1,6 @@
 """Slot-resident continuous batching over preallocated recurrent state.
 
-The wave engine (serving/engine.Engine) serves lockstep batches: every
-request in a wave is padded to the longest prompt and the longest
+A lockstep batch pads every request to the longest prompt and the longest
 ``max_new_tokens``, so one long request stalls its lane-mates and finished
 lanes ride along as dead weight.  This module turns the batch axis into B
 independent **slots**:
@@ -12,8 +11,9 @@ independent **slots**:
   machinery is needed;
 * requests wait in a bounded ``RequestQueue`` (FIFO, backpressure by
   raising ``QueueFull``, per-request deadlines);
-* admission prefills the new prompt through a B=1 scratch cache and
-  left-packs it into the free lane with a donated scatter jit
+* admission prefills the new prompt through a B=1 scratch cache
+  (``PrefillLane``, one segment at a time) and left-packs it into the
+  free lane with a donated scatter jit
   (``cache.at[:, i]``-style ``dynamic_update_slice``, no reallocation);
 * every tick runs ONE fused masked decode step across all lanes
   (steps.masked_decode_step) — free/finished lanes are carried by a per-slot
@@ -134,8 +134,8 @@ def chunk_schedule(prompt_len: int, chunk_len: int) -> list[int]:
     The point is the compiled-shape bound: every segment length is either
     ``chunk_len`` or a power of two below it, so however ragged the
     prompt mix, the chunked-prefill jit compiles at most
-    ``1 + ceil(log2(chunk_len))`` executables — unlike whole-prompt
-    admission, which compiles one per DISTINCT prompt length.  Bigger
+    ``1 + ceil(log2(chunk_len))`` executables — unlike a whole-prompt
+    prefill, which compiles one per DISTINCT prompt length.  Bigger
     segments come first, so the tail segments (the cheap ones) are what
     lands between the final decode ticks before admission."""
     if prompt_len < 0 or chunk_len < 1:
@@ -150,11 +150,13 @@ def chunk_schedule(prompt_len: int, chunk_len: int) -> list[int]:
 
 @dataclasses.dataclass
 class PrefillLane:
-    """State machine for one partially-prefilled admission (the tentpole
-    of chunked prefill): holds the request, its B=1 scratch cache
-    (checked out from the engine's scratch StatePool; returned at
-    admission, abort, or failure — ``buffers_built`` stays at capacity
-    through every path), and the remaining fixed-shape segment schedule.
+    """State machine for one partially-prefilled admission: holds the
+    request, its B=1 scratch cache (checked out from the engine's scratch
+    StatePool; returned at admission, abort, or failure — ``buffers_built``
+    stays at capacity through every path), the remaining fixed-shape
+    segment schedule, and which prefill program runs it (``whole``: one
+    segment through the whole-prompt program, for prompts chunking cannot
+    serve exactly).
 
     Lifecycle: FILLING (schedule non-empty) -> DONE (``done``: last
     chunk's sampled token is ready and the lane admits into a free slot)
@@ -165,6 +167,7 @@ class PrefillLane:
     request: Request
     cache: Any                      # B=1 scratch, owned until release
     schedule: list[int]             # remaining segment lengths
+    whole: bool = False             # one segment, whole-prompt program
     prompt: np.ndarray = None       # int32 view of request.prompt
     filled: int = 0                 # prompt tokens already prefilled
     chunks_done: int = 0

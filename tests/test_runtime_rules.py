@@ -90,7 +90,7 @@ def test_serve_exit_code(monkeypatch, capsys, prefill_raises, rc):
     if prefill_raises:
         def boom(*args, **kwargs):
             raise RuntimeError("prefill broke")
-        monkeypatch.setattr(steps, "prefill_step", boom)
+        monkeypatch.setattr(steps, "chunked_prefill_step", boom)
     argv = ["--arch", "qwen2-0.5b", "--reduced", "--requests", "2",
             "--prompt-len", "4", "--max-new", "2", "--batch-size", "2"]
     assert serve.main(argv) == rc

@@ -391,7 +391,7 @@ def test_slot_engine_per_tick_choice_respects_two_family_viability():
     from repro.core import plans
     from repro.models import registry as model_registry
     from repro.partitioning import split as p_split
-    from repro.serving import Request, SlotEngine
+    from repro.serving import EngineConfig, Request, SlotEngine
 
     cfg = dc.replace(get_arch("qwen2-0.5b").reduced(), n_layers=2,
                      d_model=64, n_heads=2, n_kv_heads=1, head_dim=16,
@@ -401,7 +401,7 @@ def test_slot_engine_per_tick_choice_respects_two_family_viability():
 
     def run(rwkv_budget):
         engine = SlotEngine(
-            model, params, n_slots=2, max_seq=32,
+            model, params, config=EngineConfig(n_slots=2, max_seq=32),
             extra_plans={"decode/wkv_fused":
                          lambda p, c, b: steps_lib.decode_step(cfg, p, c, b)})
         engine.scheduler.viable = plans.scheduler_viability({

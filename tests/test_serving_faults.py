@@ -75,8 +75,8 @@ def baseline(tiny):
     """Fault-free reference tokens for the standard request set — what
     every healthy (finish_reason='length') lane must match bit-for-bit."""
     cfg, model, params = tiny
-    engine = SlotEngine(model, params, n_slots=2, max_seq=64,
-                        queue_capacity=8)
+    engine = SlotEngine(model, params, config=EngineConfig(
+        n_slots=2, max_seq=64, queue_capacity=8))
     results = engine.serve(_requests(cfg))
     assert all(r.finish_reason == FinishReason.LENGTH for r in results)
     return {r.uid: r.tokens for r in results}
@@ -142,8 +142,8 @@ def test_guarded_step_all_false_poison_is_bit_identical(tiny):
 # ---------------------------------------------------------------------------
 def test_submit_dead_on_arrival_publishes_deadline_result(tiny):
     cfg, model, params = tiny
-    engine = SlotEngine(model, params, n_slots=2, max_seq=64,
-                        queue_capacity=4, clock=FakeClock())
+    engine = SlotEngine(model, params, clock=FakeClock(), config=EngineConfig(
+        n_slots=2, max_seq=64, queue_capacity=4))
     req = Request(9, np.array([1, 2], np.int32), max_new_tokens=2,
                   deadline_s=0.5)
     assert engine.submit(req) is False
@@ -158,18 +158,21 @@ def test_quarantine_without_budget_errors_healthy_lane_identical(
         tiny, baseline):
     cfg, model, params = tiny
     faults = FaultPlan(seed=0, faults=(LanePoison(tick=1, lane=0),))
-    engine = SlotEngine(model, params, n_slots=2, max_seq=64,
-                        queue_capacity=8, faults=faults)
+    engine = SlotEngine(model, params, config=EngineConfig(
+        n_slots=2, max_seq=64, queue_capacity=8, faults=faults))
     reqs = _requests(cfg, lens=LENS[:2], news=[6, 4])
     results = engine.serve(reqs)
-    # uid0 (lane 0) quarantined at tick 1: admit token + tick-0 token kept,
-    # the poisoned tick-1 token never recorded
-    assert results[0].finish_reason == FinishReason.ERROR
-    assert results[0].tokens.shape[-1] == 2
-    np.testing.assert_array_equal(results[0].tokens, baseline[0][:2])
+    # lanes fill in the order prefills finish, one segment per loop turn
+    # round-robin from the second lane: uid1 (9 = 8 + 1) lands in lane 0
+    # before tick 0, uid0 (5 = 4 + 1) in lane 1 before tick 1.  uid1 is
+    # quarantined at tick 1: admit token + tick-0 token kept, the
+    # poisoned tick-1 token never recorded
+    assert results[1].finish_reason == FinishReason.ERROR
+    assert results[1].tokens.shape[-1] == 2
+    np.testing.assert_array_equal(results[1].tokens, baseline[1][:2])
     # the neighbour lane never noticed
-    assert results[1].finish_reason == FinishReason.LENGTH
-    np.testing.assert_array_equal(results[1].tokens, baseline[1])
+    assert results[0].finish_reason == FinishReason.LENGTH
+    np.testing.assert_array_equal(results[0].tokens, baseline[0])
     assert engine.metrics.counter("serving/quarantined").value == 1
     assert engine.metrics.counter("serving/retries").value == 0
     assert engine.pool.stats.buffers_built == 1       # zero-alloc held
@@ -178,8 +181,9 @@ def test_quarantine_without_budget_errors_healthy_lane_identical(
 def test_quarantine_retry_regenerates_identical_tokens(tiny, baseline):
     cfg, model, params = tiny
     faults = FaultPlan(seed=0, faults=(LanePoison(tick=1, lane=0),))
-    engine = SlotEngine(model, params, n_slots=2, max_seq=64,
-                        queue_capacity=8, faults=faults, retry_budget=2)
+    engine = SlotEngine(model, params, config=EngineConfig(
+        n_slots=2, max_seq=64, queue_capacity=8, faults=faults,
+        retry_budget=2))
     results = engine.serve(_requests(cfg, lens=LENS[:2], news=[6, 4]))
     # the retried request restarts from prefill, so greedy decode
     # regenerates exactly the fault-free tokens
@@ -195,8 +199,9 @@ def test_retries_exhausted_under_persistent_poison(tiny):
     cfg, model, params = tiny
     faults = FaultPlan(seed=0, faults=tuple(
         LanePoison(tick=t, lane=0) for t in range(64)))
-    engine = SlotEngine(model, params, n_slots=1, max_seq=64,
-                        queue_capacity=4, faults=faults, retry_budget=1)
+    engine = SlotEngine(model, params, config=EngineConfig(
+        n_slots=1, max_seq=64, queue_capacity=4, faults=faults,
+        retry_budget=1))
     [res] = engine.serve(_requests(cfg, lens=[5], news=[4]))
     assert res.finish_reason == FinishReason.RETRIES_EXHAUSTED
     assert engine.metrics.counter("serving/retries").value == 1
@@ -207,8 +212,8 @@ def test_retries_exhausted_under_persistent_poison(tiny):
 def test_prefill_fault_without_budget_is_error(tiny, baseline):
     cfg, model, params = tiny
     faults = FaultPlan(seed=0, faults=(PrefillFault(uid=0),))
-    engine = SlotEngine(model, params, n_slots=2, max_seq=64,
-                        queue_capacity=8, faults=faults)
+    engine = SlotEngine(model, params, config=EngineConfig(
+        n_slots=2, max_seq=64, queue_capacity=8, faults=faults))
     results = engine.serve(_requests(cfg, lens=LENS[:2], news=[6, 4]))
     assert results[0].finish_reason == FinishReason.ERROR
     assert results[0].tokens.shape[-1] == 0
@@ -216,20 +221,21 @@ def test_prefill_fault_without_budget_is_error(tiny, baseline):
     np.testing.assert_array_equal(results[1].tokens, baseline[1])
     # injected prefill faults raise BEFORE the dispatch: the donated B=1
     # scratch survives and is never rebuilt
-    assert engine._scratch_pool.stats.buffers_built == 1
+    assert engine._scratch_pool.stats.buffers_built == engine.prefill_lanes
 
 
 def test_prefill_fault_with_budget_retries_to_length(tiny, baseline):
     cfg, model, params = tiny
     faults = FaultPlan(seed=0, faults=(PrefillFault(uid=0),))
-    engine = SlotEngine(model, params, n_slots=2, max_seq=64,
-                        queue_capacity=8, faults=faults, retry_budget=1)
+    engine = SlotEngine(model, params, config=EngineConfig(
+        n_slots=2, max_seq=64, queue_capacity=8, faults=faults,
+        retry_budget=1))
     results = engine.serve(_requests(cfg, lens=LENS[:2], news=[6, 4]))
     for r in results:
         assert r.finish_reason == FinishReason.LENGTH
         np.testing.assert_array_equal(r.tokens, baseline[r.uid])
     assert engine.metrics.counter("serving/retries").value == 1
-    assert engine._scratch_pool.stats.buffers_built == 1
+    assert engine._scratch_pool.stats.buffers_built == engine.prefill_lanes
     # the retry keeps the request's first stamp: its TTFT and queue time
     # include the failed attempt
     assert 0.0 < results[0].queue_s < results[0].ttft_s
@@ -306,6 +312,37 @@ def test_chunk_k_fault_without_budget_is_error(tiny):
     assert sp.outstanding == 0
 
 
+def test_one_segment_lane_fault_retry_token_identical(tiny):
+    """A prefill fault on a one-segment lane (a windowed prompt longer
+    than the KV ring, prefilled whole) goes through the same lane-failure
+    path: the scratch returns to the pool, the retry prefills the whole
+    prompt again, and the tokens equal an unfaulted run's."""
+    cfg, _, params = tiny
+    model = registry.build(dataclasses.replace(cfg, sliding_window=4))
+
+    def reqs():
+        return _requests(cfg, lens=[13, 7], news=[5, 3])
+    config = dict(n_slots=2, max_seq=64, queue_capacity=8)
+    clean = SlotEngine(model, params, config=EngineConfig(**config))
+    want = {r.uid: r.tokens for r in clean.serve(reqs())}
+
+    faults = FaultPlan(seed=0, faults=(PrefillFault(uid=0),))
+    engine = SlotEngine(model, params, config=EngineConfig(
+        **config, faults=faults, retry_budget=1))
+    results = engine.serve(reqs())
+    for r in results:
+        assert r.finish_reason == FinishReason.LENGTH
+        np.testing.assert_array_equal(r.tokens, want[r.uid])
+    assert engine.metrics.counter("serving/retries").value == 1
+    # both prompts outrun the 4-slot ring: whole-prompt program only
+    assert engine._prefill_chunk._cache_size() == 0
+    assert engine._prefill_sample._cache_size() == 2
+    assert engine.metrics.histogram("serving/prefill_chunk_s").count == 2
+    sp = engine._scratch_pool.stats
+    assert sp.buffers_built == sp.capacity == 2
+    assert sp.outstanding == 0
+
+
 # ---------------------------------------------------------------------------
 # Degradation ladder: watchdog downshift, shed, recovery
 # ---------------------------------------------------------------------------
@@ -314,11 +351,13 @@ def test_ladder_degrades_sheds_and_recovers(tiny):
     faults = FaultPlan(seed=0, faults=tuple(
         SlowTick(tick=t, extra_s=1e6) for t in range(3)))
     engine = SlotEngine(
-        model, params, n_slots=2, max_seq=64, queue_capacity=4,
+        model, params,
+        config=EngineConfig(
+            n_slots=2, max_seq=64, queue_capacity=4, faults=faults,
+            tick_slo_s=50.0, slo_breach_ticks=3, slo_recover_ticks=3,
+            ladder=["decode/base"]),
         extra_plans={"decode/fallback":
-                     lambda p, c, b: steps_lib.decode_step(cfg, p, c, b)},
-        faults=faults, tick_slo_s=50.0, slo_breach_ticks=3,
-        slo_recover_ticks=3, ladder=["decode/base"])
+                     lambda p, c, b: steps_lib.decode_step(cfg, p, c, b)})
     reqs = _requests(cfg, lens=[5, 9], news=[12, 12])
     # queued behind both lanes with a deadline far under the post-breach
     # tick EMA (~1e6 s): provably unmeetable once degraded -> shed
@@ -352,8 +391,9 @@ def _chaos_property(tiny, baseline, seed):
                               uids=tuple(r.uid for r in reqs),
                               n_poison=2, n_prefill=1, n_slow_burst=1,
                               slow_extra_s=0.01, n_flood=1, flood_n=2)
-    engine = SlotEngine(model, params, n_slots=2, max_seq=64,
-                        queue_capacity=4, faults=faults, retry_budget=1)
+    engine = SlotEngine(model, params, config=EngineConfig(
+        n_slots=2, max_seq=64, queue_capacity=4, faults=faults,
+        retry_budget=1))
     for ev in engine.stream(reqs):
         assert ev.finish_reason is None or ev.finish_reason in FINISH_REASONS
     done = engine.take_finished()
@@ -364,7 +404,7 @@ def _chaos_property(tiny, baseline, seed):
         if res.finish_reason == FinishReason.LENGTH:
             np.testing.assert_array_equal(res.tokens, baseline[req.uid])
     assert engine.pool.stats.buffers_built == 1
-    assert engine._scratch_pool.stats.buffers_built == 1
+    assert engine._scratch_pool.stats.buffers_built == engine.prefill_lanes
     # every TTFT stamp closed: at a first token or a tokenless end
     assert not engine._submitted
 

@@ -1,8 +1,8 @@
 """Slot-resident continuous batching (serving/slots.py + SlotEngine).
 
 The fast tests drive a micro dense model (2 layers, d=64) — they are the
-quick-loop serving smoke.  The per-family slot-vs-wave equivalence sweeps
-build full reduced() archs and carry the ``slow`` marker.
+quick-loop serving smoke.  The per-family greedy-equivalence sweeps build
+full reduced() archs and carry the ``slow`` marker.
 """
 import dataclasses
 import gc
@@ -14,8 +14,8 @@ import pytest
 from repro.configs import get_arch
 from repro.models import registry
 from repro.partitioning import split
-from repro.serving import (Engine, EngineConfig, QueueFull, Request,
-                           RequestQueue, SlotEngine, chunk_schedule)
+from repro.serving import (EngineConfig, QueueFull, Request, RequestQueue,
+                           SlotEngine, chunk_schedule)
 
 
 @pytest.fixture(autouse=True)
@@ -111,8 +111,8 @@ def test_queue_deadline_expiry():
 # ---------------------------------------------------------------------------
 def test_slot_engine_smoke_mixed_max_new(tiny):
     cfg, model, params = tiny
-    engine = SlotEngine(model, params, n_slots=3, max_seq=64,
-                        queue_capacity=4)
+    engine = SlotEngine(model, params, config=EngineConfig(
+        n_slots=3, max_seq=64, queue_capacity=4))
     reqs = _requests(cfg, [5, 9, 3, 7, 5, 9, 3, 7], [2, 8, 4, 6, 8, 1, 6, 4])
     events = []
     results = engine.serve(reqs, on_token=events.append)
@@ -131,14 +131,15 @@ def test_slot_engine_smoke_mixed_max_new(tiny):
     assert order.index(0) < order.index(1)
     # no serving-path allocation: both pools keep their build-time buffers
     assert engine.pool.stats.buffers_built == engine.pool.stats.capacity == 1
-    assert engine._scratch_pool.stats.buffers_built == 1
+    assert engine._scratch_pool.stats.buffers_built == engine.prefill_lanes
 
 
 def test_slot_engine_no_alloc_after_warmup(tiny):
     import gc
 
     cfg, model, params = tiny
-    engine = SlotEngine(model, params, n_slots=2, max_seq=64)
+    engine = SlotEngine(model, params, config=EngineConfig(
+        n_slots=2, max_seq=64))
     engine.serve(_requests(cfg, [4, 6, 4], [3, 2, 4]))           # warmup
     gc.collect()
     live0 = len(jax.live_arrays())
@@ -148,12 +149,13 @@ def test_slot_engine_no_alloc_after_warmup(tiny):
     # — every serving-path update runs through a donated jit in place
     assert len(jax.live_arrays()) <= live0
     assert (engine.pool.stats.buffers_built,
-            engine._scratch_pool.stats.buffers_built) == (1, 1)
-    # ONE resident + ONE scratch checkout for the engine's whole life: the
-    # scratch is zeroed in place inside the donated prefill jit, never
-    # returned/rebuilt
+            engine._scratch_pool.stats.buffers_built) == (1, 2)
+    # ONE resident checkout for the engine's whole life; one scratch
+    # checkout per admitted request, each returned (zeroed in place
+    # through the pool's donated reset) when its lane lands in a slot
     assert engine.pool.stats.checkouts == 1
-    assert engine._scratch_pool.stats.checkouts == 1
+    sp = engine._scratch_pool.stats
+    assert (sp.checkouts, sp.resets, sp.outstanding) == (6, 6, 0)
     # the always-on serving metrics are host-side ints/deques — populating
     # them across two serves must not have touched the device pools above
     assert engine.metrics.counter("serving/ticks").value > 0
@@ -168,13 +170,15 @@ def test_slot_engine_traced_run_token_identical(tiny):
 
     cfg, model, params = tiny
     reqs = lambda: _requests(cfg, [4, 6, 3], [3, 2, 4])
-    base = SlotEngine(model, params, n_slots=2, max_seq=64)
+    base = SlotEngine(model, params, config=EngineConfig(
+        n_slots=2, max_seq=64))
     want = [r.tokens for r in base.serve(reqs())]
 
     sink = ListSink()
     old = set_tracer(Tracer(sink))
     try:
-        traced = SlotEngine(model, params, n_slots=2, max_seq=64)
+        traced = SlotEngine(model, params, config=EngineConfig(
+            n_slots=2, max_seq=64))
         got = [r.tokens for r in traced.serve(reqs())]
     finally:
         set_tracer(old)
@@ -213,7 +217,8 @@ def test_slot_engine_ttft_on_results(tiny):
     Result next to decode_s with its queue part, and feeds the
     serving/ttft_s histogram."""
     cfg, model, params = tiny
-    engine = SlotEngine(model, params, n_slots=2, max_seq=64)
+    engine = SlotEngine(model, params, config=EngineConfig(
+        n_slots=2, max_seq=64))
     results = engine.serve(_requests(cfg, [4, 7, 3], [3, 2, 4]))
     for r in results:
         assert r.finish_reason == "length"
@@ -233,8 +238,8 @@ def test_slot_engine_ttft_on_results(tiny):
 
 def test_slot_engine_backpressure(tiny):
     cfg, model, params = tiny
-    engine = SlotEngine(model, params, n_slots=1, max_seq=64,
-                        queue_capacity=2)
+    engine = SlotEngine(model, params, config=EngineConfig(
+        n_slots=1, max_seq=64, queue_capacity=2))
     engine.submit(Request(0, np.array([1, 2], np.int32), max_new_tokens=2))
     engine.submit(Request(1, np.array([3], np.int32), max_new_tokens=2))
     with pytest.raises(QueueFull):
@@ -248,7 +253,8 @@ def test_slot_engine_backpressure(tiny):
 def test_deadline_expiry_queued_and_resident(tiny):
     cfg, model, params = tiny
     clock = FakeClock()
-    engine = SlotEngine(model, params, n_slots=1, max_seq=64, clock=clock)
+    engine = SlotEngine(model, params, clock=clock, config=EngineConfig(
+        n_slots=1, max_seq=64))
     reqs = [
         # admitted first; deadline hits mid-generation (clock ticks ~1/loop)
         Request(0, np.array([1, 2, 3], np.int32), max_new_tokens=32,
@@ -272,9 +278,10 @@ def test_deadline_expiry_queued_and_resident(tiny):
 
 def test_zero_budget_request_gets_zero_tokens(tiny):
     """max_new_tokens=0 completes without prefilling or occupying a lane —
-    matching the wave engine's per-request truncation."""
+    every request gets exactly its own token budget."""
     cfg, model, params = tiny
-    engine = SlotEngine(model, params, n_slots=2, max_seq=64)
+    engine = SlotEngine(model, params, config=EngineConfig(
+        n_slots=2, max_seq=64))
     reqs = [Request(0, np.array([1, 2], np.int32), max_new_tokens=0),
             Request(1, np.array([3, 4], np.int32), max_new_tokens=2)]
     results = engine.serve(reqs)
@@ -290,8 +297,8 @@ def test_deadline_checked_on_mid_admission_refill(tiny):
     deadline-checked, not silently served."""
     cfg, model, params = tiny
     clock = FakeClock()
-    engine = SlotEngine(model, params, n_slots=2, max_seq=64,
-                        queue_capacity=1, clock=clock)
+    engine = SlotEngine(model, params, clock=clock, config=EngineConfig(
+        n_slots=2, max_seq=64, queue_capacity=1))
     reqs = [Request(0, np.array([1, 2], np.int32), max_new_tokens=1),
             Request(1, np.array([3], np.int32), max_new_tokens=1,
                     deadline_s=0.5)]        # already past at first tick
@@ -305,7 +312,8 @@ def test_request_exceeding_lane_budget_rejected_upfront(tiny):
     """prompt_len + max_new_tokens - 1 > max_seq would scatter decode KV
     out of range (silently dropped) — rejected at submit time instead."""
     cfg, model, params = tiny
-    engine = SlotEngine(model, params, n_slots=2, max_seq=16)
+    engine = SlotEngine(model, params, config=EngineConfig(
+        n_slots=2, max_seq=16))
     with pytest.raises(ValueError, match="exceeds max_seq"):
         engine.submit(Request(0, np.arange(12, dtype=np.int32),
                               max_new_tokens=8))
@@ -316,52 +324,6 @@ def test_request_exceeding_lane_budget_rejected_upfront(tiny):
     res = engine.serve([Request(0, np.arange(12, dtype=np.int32),
                                 max_new_tokens=5)])
     assert res[0].tokens.shape == (5,)
-
-
-def test_wave_engine_pads_with_inactive_dummies(tiny):
-    """Ragged wave tails pad with zero-length dummy requests, not
-    duplicates of real work; every request gets ITS OWN token budget."""
-    cfg, model, params = tiny
-    engine = Engine(model, params, batch_size=4, max_seq=64,
-                    pool_capacity=1)
-    reqs = _requests(cfg, [6, 6, 6, 6, 6], [4, 2, 4, 4, 3])
-    results = engine.serve(reqs)
-    assert [r.uid for r in results] == [0, 1, 2, 3, 4]
-    assert [r.tokens.shape[-1] for r in results] == [4, 2, 4, 4, 3]
-
-
-# ---------------------------------------------------------------------------
-# EngineConfig (consolidated construction surface + deprecated aliases)
-# ---------------------------------------------------------------------------
-def test_engine_config_aliases_warn_and_match(tiny):
-    cfg, model, params = tiny
-    with pytest.warns(DeprecationWarning, match="deprecated"):
-        legacy = SlotEngine(model, params, n_slots=3, max_seq=64,
-                            queue_capacity=4, retry_budget=1)
-    modern = SlotEngine(model, params, config=EngineConfig(
-        n_slots=3, max_seq=64, queue_capacity=4, retry_budget=1))
-    assert legacy.config == modern.config
-    assert (legacy.n_slots, legacy.max_seq, legacy.retry_budget) == (3, 64, 1)
-    # behaviour, not just bookkeeping: same tokens either way
-    want = [r.tokens for r in modern.serve(_requests(cfg, [4, 6], [3, 2]))]
-    got = [r.tokens for r in legacy.serve(_requests(cfg, [4, 6], [3, 2]))]
-    for w, g in zip(want, got):
-        np.testing.assert_array_equal(w, g)
-    # wave engine: batch_size is the alias of n_slots
-    with pytest.warns(DeprecationWarning, match="deprecated"):
-        wave = Engine(model, params, batch_size=2, max_seq=32,
-                      pool_capacity=1)
-    assert wave.config.n_slots == wave.config.batch_size == 2
-
-
-def test_engine_config_rejects_mixed_and_unknown(tiny):
-    cfg, model, params = tiny
-    with pytest.raises(ValueError, match="not both"):
-        SlotEngine(model, params, config=EngineConfig(), n_slots=2)
-    with pytest.raises(TypeError, match="unexpected keyword"):
-        SlotEngine(model, params, bogus_knob=2)
-    with pytest.raises(TypeError, match="unexpected keyword"):
-        Engine(model, params, n_slots=2)      # a slot-only spelling
 
 
 # ---------------------------------------------------------------------------
@@ -385,22 +347,23 @@ def test_chunk_schedule_fixed_shapes():
 
 
 def test_chunked_prefill_token_identity_and_one_shape(tiny):
-    """Chunking changes scheduling, not math: greedy tokens match
-    whole-prompt admission bit-for-bit, the chunk jit compiles exactly one
-    executable per DISTINCT segment length, and both pools keep their
-    build-time buffers through checkout/give_back lane churn."""
+    """Chunking changes scheduling, not math: greedy tokens match the
+    cacheless full-sequence forward bit-for-bit, the chunk jit compiles
+    exactly one executable per DISTINCT segment length (and the
+    whole-prompt program none), and both pools keep their build-time
+    buffers through checkout/give_back lane churn."""
     cfg, model, params = tiny
     lens, news = [5, 29, 3, 13, 7, 21], [4, 6, 3, 5, 2, 4]
-    whole = SlotEngine(model, params, config=EngineConfig(
-        n_slots=3, max_seq=64))
-    want = whole.serve(_requests(cfg, lens, news, seed=3))
+    reqs = _requests(cfg, lens, news, seed=3)
+    want = _forward_greedy(model, params, reqs, 64)
 
     engine = SlotEngine(model, params, config=EngineConfig(
         n_slots=3, max_seq=64, prefill_chunk_len=8, prefill_lanes=2))
-    got = engine.serve(_requests(cfg, lens, news, seed=3))
+    got = engine.serve(reqs)
     for w, g in zip(want, got):
         assert g.finish_reason == "length"
-        np.testing.assert_array_equal(w.tokens, g.tokens)
+        np.testing.assert_array_equal(w, g.tokens)
+    assert engine._prefill_sample._cache_size() == 0
     segs = set()
     for l in lens:
         segs.update(chunk_schedule(l, 8))
@@ -469,6 +432,91 @@ def test_chunked_rejects_invalid_config(tiny):
             n_slots=2, max_seq=64, prefill_chunk_len=4, prefill_lanes=0))
 
 
+def test_default_chunk_len_is_capped_at_the_exact_length(tiny):
+    """Unset, the chunk length is CHUNK_LEN, lowered to the longest prompt
+    chunking serves exactly: the cache seq axis, or the KV ring of a
+    windowed model.  An explicit length past that still raises."""
+    from repro.serving.engine import CHUNK_LEN
+
+    cfg, model, params = tiny
+    chunk = lambda m, **kw: SlotEngine(m, params, config=EngineConfig(
+        n_slots=1, **kw))._chunk_len
+    assert chunk(model, max_seq=4 * CHUNK_LEN) == CHUNK_LEN
+    assert chunk(model, max_seq=64) == 64
+    assert chunk(_windowed(cfg, 8), max_seq=64) == 8
+    assert chunk(model, max_seq=64, prefill_chunk_len=16) == 16
+    with pytest.raises(ValueError, match="prefill_chunk_len 9 outside"):
+        chunk(_windowed(cfg, 8), max_seq=64, prefill_chunk_len=9)
+
+
+def _windowed(cfg, window):
+    """The tiny model with a sliding-window KV ring of ``window`` slots
+    (same parameter shapes as ``tiny``)."""
+    return registry.build(dataclasses.replace(cfg, sliding_window=window))
+
+
+def test_windowed_long_prompt_takes_one_segment_lane(tiny):
+    """A windowed prompt longer than the KV ring cannot be chunked
+    exactly (the ring evicts keys a mid-chunk query still needs), so it
+    prefills as ONE segment through the whole-prompt program; prompts
+    that fit the ring still chunk.  Either way the tokens are the
+    windowed model's full-sequence greedy tokens, and the scratch pool
+    stays at capacity."""
+    cfg, _, params = tiny
+    model = _windowed(cfg, 8)
+    lens, news = [13, 5, 21, 9], [6, 10, 4, 8]
+    reqs = _requests(cfg, lens, news, seed=2)
+    engine = SlotEngine(model, params, config=EngineConfig(
+        n_slots=2, max_seq=64))
+    got = engine.serve(reqs)
+    for want, g in zip(_forward_greedy(model, params, reqs, 64), got):
+        assert g.finish_reason == "length"
+        np.testing.assert_array_equal(want, g.tokens)
+    # one whole-prompt executable per long prompt length, chunk shapes
+    # only for the prompt that fits the ring (5 = 4 + 1)
+    assert engine._prefill_sample._cache_size() == 3
+    assert engine._prefill_chunk._cache_size() == 2
+    assert engine.metrics.histogram("serving/prefill_chunk_s").count == 3 + 2
+    sp = engine._scratch_pool.stats
+    assert sp.buffers_built == sp.capacity == engine.prefill_lanes
+    assert sp.outstanding == 0
+    assert engine.pool.stats.buffers_built == 1
+
+
+def test_vision_tokens_served_by_the_default_engine():
+    """A vision-token model is served by a default SlotEngine: its prompts
+    take one-segment lanes through the whole-prompt program, with zero
+    vision embeddings, and match a manual prefill-and-decode."""
+    from repro import steps
+
+    cfg = get_arch("internvl2-1b").reduced()
+    assert cfg.n_vis_tokens
+    model = registry.build(cfg)
+    params, _ = split(model.init(jax.random.PRNGKey(0)))
+    reqs = _requests(cfg, [6, 9, 4], [4, 3, 5], seed=4)
+    engine = SlotEngine(model, params, config=EngineConfig(
+        n_slots=2, max_seq=32))
+    got = engine.serve(reqs)
+    assert engine._prefill_chunk._cache_size() == 0
+    assert engine._prefill_sample._cache_size() == 3
+
+    prefill = jax.jit(lambda p, c, b: steps.prefill_step(cfg, p, c, b))
+    decode = jax.jit(lambda p, c, b: steps.decode_step(cfg, p, c, b))
+    vis = np.zeros((1, cfg.n_vis_tokens, cfg.vis_dim), cfg.dtype)
+    for req, res in zip(reqs, got):
+        cache, _ = split(model.init_cache(1, 32))
+        logits, cache = prefill(params, cache, {"tokens": req.prompt[None],
+                                                "vis_embeds": vis})
+        tok = steps.greedy_sample(logits)[:, 0]
+        want = []
+        for _ in range(req.max_new_tokens):
+            want.append(int(tok[0]))
+            logits, cache = decode(params, cache, {"tokens": tok})
+            tok = steps.greedy_sample(logits)
+        assert res.finish_reason == "length"
+        np.testing.assert_array_equal(res.tokens, np.array(want, np.int32))
+
+
 # ---------------------------------------------------------------------------
 # Engine spans: the tick and the chunk split into their phases, and one
 # serve/request interval per request
@@ -495,15 +543,21 @@ def _assert_phases(records, parent, names):
 
 @pytest.mark.parametrize("chunk", [None, 4], ids=["whole", "chunked"])
 def test_engine_spans_split_tick_and_chunk(tiny, chunk):
-    """Traced serving, whole-prompt and chunked admission: every tick has
+    """Traced serving, one-segment and chunked lanes: every tick has
     prepare/dispatch/sync phases and every chunk dispatch/sync; each
     request has one serve/request whose queue and lane parts add up to
     its TTFT; no engine span is open while the client runs; the chunk
-    events keep their schema; tokens and pools are as untraced."""
+    events keep their schema; tokens and pools are as untraced.  The
+    ``whole`` case serves a model whose 2-slot KV ring every prompt
+    outruns, so each prompt is one whole-prompt segment."""
     from repro.obs import ListSink, Tracer, get_tracer, set_tracer
 
     cfg, model, params = tiny
+    if chunk is None:
+        model = _windowed(cfg, 2)
     lens, news = [5, 11, 3, 9], [4, 3, 5, 2]
+    schedule = ((lambda n: [n]) if chunk is None
+                else (lambda n: chunk_schedule(n, chunk)))
 
     def serve(on_token=None):
         engine = SlotEngine(model, params, config=EngineConfig(
@@ -540,25 +594,24 @@ def test_engine_spans_split_tick_and_chunk(tiny, chunk):
 
     chunks, events = named("serve/chunk"), named("serve/prefill_chunk")
     starts = named("serve/prefill_start")
-    if chunk is None:
-        assert not chunks and not events and not starts
-    else:
-        n_chunks = sum(len(chunk_schedule(n, chunk)) for n in lens)
-        assert len(chunks) == len(events) == n_chunks
-        assert len(starts) == len(lens)
-        for c, e in zip(chunks, events):
-            _assert_phases(recs, c, ["serve/chunk/dispatch",
-                                     "serve/chunk/sync"])
-            assert set(c["attrs"]) == {"uid", "chunk", "seg_len"}
-            assert e["type"] == "event" and e["parent"] is None
-            assert set(e["attrs"]) == {"uid", "chunk", "seg_len", "filled",
-                                       "chunk_s"}
-            assert [c["attrs"][k] for k in ("uid", "chunk", "seg_len")] == \
-                [e["attrs"][k] for k in ("uid", "chunk", "seg_len")]
-            # the event is stamped once the chunk's token is ready
-            assert _end(c) <= e["ts"]
-        for e in starts:
-            assert set(e["attrs"]) == {"uid", "prompt_len", "n_chunks"}
+    n_chunks = sum(len(schedule(n)) for n in lens)
+    assert len(chunks) == len(events) == n_chunks
+    assert len(starts) == len(lens)
+    for c, e in zip(chunks, events):
+        _assert_phases(recs, c, ["serve/chunk/dispatch",
+                                 "serve/chunk/sync"])
+        assert set(c["attrs"]) == {"uid", "chunk", "seg_len"}
+        assert e["type"] == "event" and e["parent"] is None
+        assert set(e["attrs"]) == {"uid", "chunk", "seg_len", "filled",
+                                   "chunk_s"}
+        assert [c["attrs"][k] for k in ("uid", "chunk", "seg_len")] == \
+            [e["attrs"][k] for k in ("uid", "chunk", "seg_len")]
+        # the event is stamped once the chunk's token is ready
+        assert _end(c) <= e["ts"]
+    for e in starts:
+        assert set(e["attrs"]) == {"uid", "prompt_len", "n_chunks"}
+        assert e["attrs"]["n_chunks"] == len(
+            schedule(lens[e["attrs"]["uid"]]))
 
     reqs = named("serve/request")
     assert sorted(r["attrs"]["uid"] for r in reqs) == list(range(len(lens)))
@@ -572,8 +625,7 @@ def test_engine_spans_split_tick_and_chunk(tiny, chunk):
         assert r["dur_s"] == pytest.approx(res.ttft_s, abs=1e-9)
         assert a["queue_s"] == pytest.approx(res.queue_s, abs=1e-9)
         assert a["prompt_len"] == lens[a["uid"]]
-        assert a["chunks"] == (1 if chunk is None else
-                               len(chunk_schedule(lens[a["uid"]], chunk)))
+        assert a["chunks"] == len(schedule(lens[a["uid"]]))
     admits = {r["attrs"]["uid"]: r["attrs"]["ttft_s"]
               for r in named("serve/admit")}
     assert admits == pytest.approx({u: r.ttft_s for u, r in results.items()})
@@ -584,16 +636,18 @@ def test_engine_spans_split_tick_and_chunk(tiny, chunk):
                                   "jamba-1.5-large-398b",  # ssm (mamba)
                                   "rwkv6-3b"])             # rwkv
 def test_chunked_prefill_token_identity_per_family(arch):
-    """Chunked admission is token-identical to whole-prompt admission for
-    every serving family — attention replays the exact positions through
-    the chunk mask, rwkv/mamba prefill FROM their cache state natively —
-    and both are the greedy tokens of the cacheless full-sequence
-    forward, so a state layout the prefill and the decode tick share
-    (rwkv6's packed wkv pool) cannot agree with itself by mistake."""
+    """Admission is token-identical across chunk lengths for every serving
+    family — attention replays the exact positions through the chunk
+    mask, rwkv/mamba prefill FROM their cache state natively — and both
+    are the greedy tokens of the cacheless full-sequence forward, so a
+    state layout the prefill and the decode tick share (rwkv6's packed
+    wkv pool) cannot agree with itself by mistake."""
     cfg = get_arch(arch).reduced()
     model = registry.build(cfg)
     params, _ = split(model.init(jax.random.PRNGKey(0)))
     reqs = _requests(cfg, [4, 10, 6, 8], [3, 8, 2, 5], seed=1)
+    # chunk 32 (the default, capped at max_seq): 10 -> [8, 2]; chunk 4:
+    # 10 -> [4, 4, 2]
     whole = SlotEngine(model, params, config=EngineConfig(
         n_slots=2, max_seq=32)).serve(reqs)
     chunked = SlotEngine(model, params, config=EngineConfig(
@@ -624,24 +678,24 @@ def _forward_greedy(model, params, reqs, width):
 
 
 # ---------------------------------------------------------------------------
-# Slot-vs-wave greedy equivalence per model family
+# Slot engine vs the unpadded per-request greedy reference, per family
 # ---------------------------------------------------------------------------
 @pytest.mark.slow
 @pytest.mark.parametrize("arch", ["qwen2-0.5b",            # dense
                                   "jamba-1.5-large-398b",  # ssm (mamba)
                                   "rwkv6-3b"])             # rwkv
-def test_slot_vs_wave_equivalence(arch):
+def test_slot_engine_greedy_equivalence(arch):
     """The slot engine's greedy outputs are token-identical to the
-    unpadded per-request reference (the wave engine at batch_size=1) on a
-    ragged request set — per-lane prefill, per-lane positions and the
-    active-mask select are all exact."""
+    unpadded per-request reference (``_forward_greedy``) on a ragged
+    request set with more requests than slots — per-lane prefill,
+    per-lane positions, lane reuse and the active-mask select are all
+    exact."""
     cfg = get_arch(arch).reduced()
     model = registry.build(cfg)
     params, _ = split(model.init(jax.random.PRNGKey(0)))
     reqs = _requests(cfg, [4, 10, 6, 8], [3, 8, 2, 5], seed=1)
-    ref = Engine(model, params, batch_size=1, max_seq=32,
-                 pool_capacity=1).serve(reqs)
-    out = SlotEngine(model, params, n_slots=2, max_seq=32).serve(reqs)
+    ref = _forward_greedy(model, params, reqs, 32)
+    out = SlotEngine(model, params, config=EngineConfig(
+        n_slots=2, max_seq=32)).serve(reqs)
     for r, o in zip(ref, out):
-        assert np.array_equal(r.tokens, o.tokens), (r.uid, r.tokens,
-                                                    o.tokens)
+        assert np.array_equal(r, o.tokens), (o.uid, r, o.tokens)

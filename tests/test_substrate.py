@@ -105,30 +105,33 @@ def test_serving_engine_end_to_end():
     from repro.configs import get_arch
     from repro.models import registry
     from repro.partitioning import split
-    from repro.serving import Engine, Request
+    from repro.serving import EngineConfig, Request, SlotEngine
 
     cfg = get_arch("qwen2-0.5b").reduced()
     m = registry.build(cfg)
     params, _ = split(m.init(jax.random.PRNGKey(0)))
-    eng = Engine(m, params, batch_size=2, max_seq=32, pool_capacity=2)
+    eng = SlotEngine(m, params, config=EngineConfig(n_slots=2, max_seq=32))
     rng = np.random.default_rng(0)
     reqs = [Request(i, rng.integers(0, cfg.vocab, (6,)).astype(np.int32),
                     max_new_tokens=4) for i in range(3)]
     res = eng.serve(reqs)
     assert len(res) == 3
     assert all(r.tokens.shape == (4,) for r in res)
-    assert eng.pool.stats.outstanding == 0
-    assert eng.pool.stats.checkouts == 2   # two waves
+    # one resident cache for the engine's life; one scratch checkout per
+    # request, each returned when its lane lands in a slot
+    assert (eng.pool.stats.checkouts, eng.pool.stats.outstanding) == (1, 1)
+    sp = eng._scratch_pool.stats
+    assert (sp.buffers_built, sp.checkouts, sp.outstanding) == (2, 3, 0)
 
 
 @pytest.mark.slow
 def test_serving_engine_greedy_matches_manual_decode():
-    """Engine output == manual prefill+greedy loop with the raw model."""
+    """SlotEngine output == manual prefill+greedy loop with the raw
+    model."""
     from repro.configs import get_arch
     from repro.models import registry
     from repro.partitioning import split
-    from repro.serving import Engine, Request
-    from repro import steps
+    from repro.serving import EngineConfig, Request, SlotEngine
 
     cfg = get_arch("yi-9b").reduced()
     m = registry.build(cfg)
@@ -136,7 +139,7 @@ def test_serving_engine_greedy_matches_manual_decode():
     rng = np.random.default_rng(1)
     prompt = rng.integers(0, cfg.vocab, (5,)).astype(np.int32)
 
-    eng = Engine(m, params, batch_size=1, max_seq=16)
+    eng = SlotEngine(m, params, config=EngineConfig(n_slots=1, max_seq=16))
     out = eng.serve([Request(0, prompt, max_new_tokens=3)])[0].tokens
 
     cache, _ = split(m.init_cache(1, 16))
